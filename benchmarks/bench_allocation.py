@@ -28,7 +28,6 @@ from common import SMOKE, emit
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 from repro.configs.base import FLConfig
 from repro.core import allocation as AL
 from repro.core import allocation_jax as AJ
@@ -65,7 +64,7 @@ def solve_grid(probs, method, max_iters, label, point_names):
     ``iters_used`` derived) plus a ``{label}`` grid row whose derived
     field carries the early-exit speedup vs the SAME grid solved
     fixed-trip."""
-    with enable_x64():
+    with jax.enable_x64():
         grid = AJ.stack_problems(probs)
     sol = AJ.solve_batched(grid, method, max_iters=max_iters)
     jax.block_until_ready(sol)                        # compile
@@ -108,7 +107,7 @@ def main() -> None:
     b = 8 if SMOKE else 64
     k = 8
     prob = _problem(k)
-    with enable_x64():
+    with jax.enable_x64():
         fades = CH.block_fading_trajectory(
             jax.random.PRNGKey(1), jnp.asarray(prob.gains), b,
             rho=0.8, shadow_std_db=4.0)

@@ -44,6 +44,7 @@ from common import SMOKE, emit
 from repro.configs.base import FLConfig
 from repro.core import transport as TR
 from repro.launch import shardings as SH
+from repro.launch.mesh import make_mesh
 from repro.wire import format as fmt
 
 K = 32
@@ -64,7 +65,7 @@ def main() -> None:
     fl = FLConfig()
     bits = fl.quant_bits
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ('data',))
+    mesh = make_mesh((n_dev,), ('data',))
     emit('dist_mesh', 0.0, f'{n_dev} devices as (data={n_dev}) '
          f'[{jax.default_backend()}]')
 

@@ -23,6 +23,8 @@ import json
 import os
 import time
 
+import jax
+
 from repro.configs.base import FLConfig
 from repro.configs.registry import get_arch, get_shape
 from repro.launch.mesh import make_production_mesh
@@ -61,7 +63,7 @@ def apply_variant(cfg, fl, name: str):
 
 def measure(cfg, fl, shape, mesh) -> dict:
     g_full = cfg.n_layers // len(cfg.layer_pattern)
-    with mesh:
+    with jax.set_mesh(mesh):
         d1 = dr._compile_and_analyze(dr._depth_clone(cfg, 1), shape, mesh,
                                      fl, unroll=True)
         d2 = dr._compile_and_analyze(dr._depth_clone(cfg, 2), shape, mesh,

@@ -47,7 +47,7 @@ closed forms (shared with the reference via ``repro.core.alloc_common``)
 need float64 — the f64 guard constants ``EXP_CAP=600`` / ``POW_CAP=500``
 / ``H_FLOOR=-1e150`` all overflow float32.  The host-facing wrappers
 (``solve``, ``solve_batched``) therefore run under
-``jax.experimental.enable_x64`` and match the NumPy reference to tight
+``jax.enable_x64`` and match the NumPy reference to tight
 tolerances; ``solve_traceable`` embedded in an f32 program instead
 substitutes f32-safe caps (``_caps``) and keeps the same argmin
 structure at reduced precision.
@@ -61,7 +61,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.configs.base import FLConfig
 from repro.core import alloc_common as AC
@@ -691,7 +690,7 @@ def solve_batched(prob: JaxAllocationProblem, method: str = 'alternating',
     ``while_loop`` steps until every element converged, select-freezing
     finished elements — still bit-identical to a loop of single solves.
     """
-    with enable_x64():
+    with jax.enable_x64():
         return _solve_batched_jit(prob, method, max_iters, tol, n_grid,
                                   newton_iters, early_exit, inner_tol)
 
@@ -714,7 +713,7 @@ def solve_from_stats(g2, gb2, v, d2, gains, p_w, dim: int, fl: FLConfig,
     """One jitted dispatch from the devices' scalar report to the round's
     allocation — the ``allocation_backend='jax'`` path of the training
     drivers (no host NumPy between the stats and (q, p))."""
-    with enable_x64():
+    with jax.enable_x64():
         return _solve_stats_jit(g2, gb2, v, d2, gains, p_w, dim, fl,
                                 method, max_iters, tol, early_exit)
 
@@ -726,7 +725,7 @@ def solve(prob, method: str = 'alternating', max_iters: int = 6,
     problem (or a pre-built pytree), solves on-device under x64, returns
     the host :class:`Allocation` with ``info['iters_used']`` /
     ``info['exit_reason']`` reporting the solver effort."""
-    with enable_x64():
+    with jax.enable_x64():
         jp = from_reference(prob) if isinstance(prob, AllocationProblem) \
             else prob
         sol = _solve_jit(jp, method=method, max_iters=max_iters, tol=tol,

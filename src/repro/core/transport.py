@@ -210,9 +210,7 @@ def _resolve_collective(collective: Optional[str], wire: str, mesh,
         if mesh is None:
             raise ValueError("collective='sharded' requires a mesh "
                              "(training/distributed.py passes it through)")
-        if client_axes is None:
-            client_axes = kops.default_client_axes(mesh)
-        return collective, tuple(client_axes)
+        return collective, kops.resolve_client_axes(mesh, client_axes)
     return collective, None
 
 
@@ -893,7 +891,7 @@ def spfl_aggregate_tree(grads_tree, gbar_tree, q: Array, p: Array,
         payload = K * (sign_bits + mod_bits)
     diag = RoundTelemetry(
         sign_ok, mod_ok, sign_ok,
-        jnp.asarray(payload + retx * sign_bits, jnp.float32),
+        jnp.asarray(float(payload) + retx * sign_bits, jnp.float32),
         retx, **extras)
     return ghat, stats, diag
 

@@ -46,13 +46,22 @@ def synth_cifar(n: int, seed: int = 0, n_classes: int = 10
     return images, labels.astype(np.int32)
 
 
+def real_cifar_present(data_dir: str = 'data/cifar-10') -> bool:
+    """Whether ``load_image_dataset`` will read real CIFAR-10 binary
+    batches from ``data_dir`` (otherwise it generates SynthCIFAR)."""
+    return all(os.path.exists(f) for f in _cifar_files(data_dir))
+
+
+def _cifar_files(data_dir: str):
+    return ([os.path.join(data_dir, f'data_batch_{i}') for i in range(1, 6)]
+            + [os.path.join(data_dir, 'test_batch')])
+
+
 def _load_real_cifar(data_dir: str):
-    files = [os.path.join(data_dir, f'data_batch_{i}') for i in range(1, 6)]
-    test = os.path.join(data_dir, 'test_batch')
-    if not all(os.path.exists(f) for f in files + [test]):
+    if not real_cifar_present(data_dir):
         return None
     xs, ys = [], []
-    for f in files + [test]:
+    for f in _cifar_files(data_dir):
         with open(f, 'rb') as fh:
             d = pickle.load(fh, encoding='bytes')
         xs.append(np.asarray(d[b'data'], np.float32))

@@ -33,7 +33,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.quantize import knob_step
@@ -62,6 +61,23 @@ def default_client_axes(mesh) -> Tuple[str, ...]:
     drift apart."""
     ca = tuple(a for a in mesh.axis_names if a != 'model')
     return ca or tuple(mesh.axis_names)
+
+
+def resolve_client_axes(mesh, client_axes=None) -> Tuple[str, ...]:
+    """The client axes a sharded collective runs over (default:
+    :func:`default_client_axes`), checked to be Auto-typed: the sharded
+    paths are GSPMD programs (sharding constraints, shard_map with
+    replicated outputs), which Explicit axes — ``jax.make_mesh``'s
+    default — reject.  Build meshes with ``launch.mesh.make_mesh``."""
+    client_axes = (default_client_axes(mesh) if client_axes is None
+                   else tuple(client_axes))
+    types = dict(zip(mesh.axis_names, mesh.axis_types))
+    bad = [a for a in client_axes
+           if types[a] != jax.sharding.AxisType.Auto]
+    if bad:
+        raise ValueError(f'client mesh axes {bad} must be Auto-typed '
+                         '(build the mesh with launch.mesh.make_mesh)')
+    return client_axes
 
 
 def _n_shards(mesh, client_axes) -> int:
@@ -257,6 +273,17 @@ def _spfl_aggregate_packed_jnp(sign_payload: Array, qidx_payload: Array,
     return acc, votes
 
 
+def aggregate_atol(weight, gmax, gbar) -> float:
+    """The decode-once f32 contract as an absolute tolerance: a couple of
+    ulp per client contribution, accumulated — 4 eps x sum_k w_k
+    max(gmax_k, max gbar).  The kernel, its jnp twin and the sharded
+    psum agree within it; real decode bugs land at the knob-step scale,
+    orders of magnitude above."""
+    scale = float(jnp.sum(jnp.asarray(weight)
+                          * jnp.maximum(jnp.asarray(gmax), jnp.max(gbar))))
+    return 4 * float(jnp.finfo(jnp.float32).eps) * max(scale, 1.0)
+
+
 def spfl_aggregate_packed(sign_payload: Array, qidx_payload: Array,
                           gbar: Array, gmin: Array, gmax: Array,
                           mod_ok: Array, weight: Array, sign_ok: Array,
@@ -320,7 +347,7 @@ def spfl_aggregate_packed(sign_payload: Array, qidx_payload: Array,
     gb = gb.reshape(-1, wire_fmt.GROUP)
 
     def col(x, dt) -> Array:
-        return jnp.asarray(x).astype(dt).reshape(k, 1)
+        return jnp.asarray(x).astype(dt).reshape(k)
 
     # knob step precomputed with the analytic dequantizer's own
     # quantize.knob_step — an in-kernel constant division would
@@ -383,8 +410,7 @@ def spfl_aggregate_packed_sharded(sign_payload: Array, qidx_payload: Array,
     zero-weight, vote-gated-off dummy clients whose contributions are
     exact zeros in both domains.
     """
-    client_axes = (default_client_axes(mesh) if client_axes is None
-                   else tuple(client_axes))
+    client_axes = resolve_client_axes(mesh, client_axes)
     shards = _n_shards(mesh, client_axes)
     axes = _axes_arg(client_axes)
     k = sign_payload.shape[0]
@@ -424,8 +450,8 @@ def spfl_aggregate_packed_sharded(sign_payload: Array, qidx_payload: Array,
                 return acc, jax.lax.psum(votes, axes)
         return (acc,)
 
-    out = shard_map(local, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=False)(
+    out = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_specs, check_vma=False)(
         sign_payload, qidx_payload, gbar, gmin, gmax, mod_ok, weight,
         sign_ok)
     return out[0], (out[1] if votes_on else None)
@@ -481,7 +507,8 @@ def corrupt_fold_words(key, words: Array, ber,
         seeds, off, thresh.reshape(k, 1),
         allf.astype(jnp.uint32).reshape(k, 1),
         padded, n_words=w_n, interpret=interpret)
-    return rx[:, :w_n], fold.reshape(k), flips.reshape(k)
+    return (rx[:, :w_n], wire_fmt.xor_fold(fold),
+            jnp.sum(flips, axis=1, dtype=jnp.int32))
 
 
 def _corrupt_fold_words_sharded(key, words: Array, ber, mesh, client_axes,
@@ -489,8 +516,7 @@ def _corrupt_fold_words_sharded(key, words: Array, ber, mesh, client_axes,
     """Shard-local corrupt+fold: pads K to the shard grid, runs the
     fused pass per shard at that shard's global word offset, returns the
     client-sharded results (bit-identical to the gathered draw)."""
-    client_axes = (default_client_axes(mesh) if client_axes is None
-                   else tuple(client_axes))
+    client_axes = resolve_client_axes(mesh, client_axes)
     shards = _n_shards(mesh, client_axes)
     axes = _axes_arg(client_axes)
     k, w_n = words.shape
@@ -507,11 +533,11 @@ def _corrupt_fold_words_sharded(key, words: Array, ber, mesh, client_axes,
                                   use_kernel=use_kernel,
                                   word0=row0 * jnp.uint32(w_n))
 
-    rx, fold, flips = shard_map(
+    rx, fold, flips = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(*([None] * key_arr.ndim)), P(axes, None), P(axes)),
         out_specs=(P(axes, None), P(axes), P(axes)),
-        check_rep=False)(key_arr, padded, ber_p)
+        check_vma=False)(key_arr, padded, ber_p)
     return rx[:k], fold[:k], flips[:k]
 
 
@@ -527,19 +553,18 @@ def fold_words(words: Array, interpret: bool | None = None,
     making GSPMD gather the payload rows)."""
     interpret = default_interpret() if interpret is None else interpret
     if mesh is not None:
-        client_axes = (default_client_axes(mesh) if client_axes is None
-                       else tuple(client_axes))
+        client_axes = resolve_client_axes(mesh, client_axes)
         shards = _n_shards(mesh, client_axes)
         axes = _axes_arg(client_axes)
         k = words.shape[0]
         k_pad = -(-k // shards) * shards
         padded = _pad_clients(words, k_pad - k)
-        out = shard_map(
+        out = jax.shard_map(
             lambda wl: fold_words(wl, interpret=interpret),
             mesh=mesh, in_specs=(P(axes, None),), out_specs=P(axes),
-            check_rep=False)(padded)
+            check_vma=False)(padded)
         return out[:k]
     k, w_n = words.shape
     w_pad = -(-w_n // wk.BLOCK_FOLD_WORDS) * wk.BLOCK_FOLD_WORDS
     padded = jnp.pad(words.astype(jnp.uint32), ((0, 0), (0, w_pad - w_n)))
-    return wk.fold_words_2d(padded, interpret=interpret).reshape(k)
+    return wire_fmt.xor_fold(wk.fold_words_2d(padded, interpret=interpret))

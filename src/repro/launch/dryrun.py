@@ -310,7 +310,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
 
     fl = FLConfig(n_devices=32 if multi_pod else 16)
     mesh = make_production_mesh(multi_pod=multi_pod)
-    with mesh:
+    with jax.set_mesh(mesh):
         t0 = time.time()
         full = _compile_and_analyze(cfg, shape, mesh, fl, unroll=False)
         record.update(full)

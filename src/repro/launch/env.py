@@ -1,4 +1,5 @@
-"""Computation-environment hygiene: platform, precision, XLA flags.
+"""Computation-environment hygiene: platform, precision, XLA flags,
+compile cache.
 
 The "hardware truth" prerequisite (ROADMAP): before any benchmark or
 training run touches a device, pin the platform/precision/XLA-flag state
@@ -13,53 +14,56 @@ of a driver's ``main()`` and pass :func:`resolved_state` into the run
 manifest (``repro.obs.sink.run_manifest`` does the latter
 automatically).
 
-Environment overrides (all optional): ``REPRO_PLATFORM`` (cpu|gpu|tpu),
+Environment overrides (all optional): ``REPRO_PLATFORM`` (cpu|tpu),
 ``REPRO_X64`` (0|1), ``REPRO_HOST_DEVICES`` (int) — the knobs CI and
-benchmark boxes set without code changes.
+benchmark boxes set without code changes — and
+``JAX_COMPILATION_CACHE_DIR``, which jax reads itself: when it is set the
+persistent compile cache lives there, otherwise at the fixed, git-ignored
+``<checkout>/.jax_cache`` (a fixed path, because the path is part of the
+cache's key).
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Any, Dict, Optional
 
 import jax
 
-# GPU flags per the bayespec hygiene snippet (jax gpu perf tips); applied
-# only when the platform is explicitly set to gpu
-_GPU_XLA_FLAGS = (
-    '--xla_gpu_triton_gemm_any=True '
-    '--xla_gpu_enable_latency_hiding_scheduler=true '
-)
+# <checkout>/.jax_cache: src/repro/launch/env.py -> four levels up
+CHECKOUT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / '.jax_cache')
 
 # what configure() resolved this process to — the manifest's 'env' block
 _STATE: Dict[str, Any] = {'configured': False}
 
 
-def _append_xla_flags(flags: str) -> None:
-    cur = os.environ.get('XLA_FLAGS', '')
-    for f in flags.split():
-        if f.split('=')[0] not in cur:
-            cur = (cur + ' ' + f).strip()
-    os.environ['XLA_FLAGS'] = cur
-
-
 def set_platform(platform: Optional[str] = None) -> Optional[str]:
-    """Pin the backend ('cpu' | 'gpu' | 'tpu').  Only takes effect before
-    the first device use; ``None`` leaves jax's own resolution in place
-    (and records that)."""
+    """Pin the backend ('cpu' | 'tpu').  Only takes effect before the
+    first device use; ``None`` leaves jax's own resolution in place (and
+    records that)."""
     platform = platform or os.environ.get('REPRO_PLATFORM') or None
     if platform:
         jax.config.update('jax_platform_name', platform)
-        if platform == 'gpu':
-            _append_xla_flags(_GPU_XLA_FLAGS)
     return platform
+
+
+def set_compile_cache() -> str:
+    """Place jax's persistent compilation cache and return its directory.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is left to jax (no other
+    directory is set in code); otherwise the cache goes to
+    :data:`CHECKOUT_CACHE_DIR`.  Must run before the first compile."""
+    env_dir = os.environ.get('JAX_COMPILATION_CACHE_DIR')
+    if env_dir:
+        return env_dir
+    jax.config.update('jax_compilation_cache_dir', CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR
 
 
 def enable_x64(use_x64: Optional[bool] = None) -> bool:
     """Default-dtype precision.  The repo's allocation closed forms
-    overflow f32 and re-enter x64 locally (``jax.experimental.
-    enable_x64``); this global knob is for whole-process x64 runs
-    (JAX_ENABLE_X64=1 / REPRO_X64=1 honored when unset)."""
+    overflow f32 and re-enter x64 locally (``with jax.enable_x64():``);
+    this global knob is for whole-process x64 runs (JAX_ENABLE_X64=1 /
+    REPRO_X64=1 honored when unset)."""
     if use_x64 is None:
         use_x64 = os.environ.get(
             'REPRO_X64', os.environ.get('JAX_ENABLE_X64', '0')) == '1'
@@ -93,6 +97,7 @@ def configure(platform: Optional[str] = None,
         platform=set_platform(platform),
         x64=enable_x64(use_x64),
         host_device_count=set_host_device_count(host_device_count),
+        compile_cache_dir=set_compile_cache(),
         xla_flags=os.environ.get('XLA_FLAGS', ''),
         jax_platforms=os.environ.get('JAX_PLATFORMS', ''),
     )

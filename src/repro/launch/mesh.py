@@ -12,6 +12,7 @@ sees the real device count).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 SINGLE_POD_SHAPE = (16, 16)
 SINGLE_POD_AXES = ('data', 'model')
@@ -19,10 +20,19 @@ MULTI_POD_SHAPE = (2, 16, 16)
 MULTI_POD_AXES = ('pod', 'data', 'model')
 
 
+def make_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto-typed axes: the launch shardings, the
+    models' sharding constraints and the sharded collectives are GSPMD
+    programs, not sharding-in-types ones (jax's default Explicit axes
+    reject them)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = MULTI_POD_AXES if multi_pod else SINGLE_POD_AXES
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def client_axes(mesh: jax.sharding.Mesh) -> tuple:
@@ -45,4 +55,4 @@ def n_clients(mesh: jax.sharding.Mesh) -> int:
 def make_host_mesh() -> jax.sharding.Mesh:
     """Whatever devices exist locally, as a 1-D 'data' mesh (CPU tests)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ('data',))
+    return make_mesh((n,), ('data',))

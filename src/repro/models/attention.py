@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import apply_rope, dense_init, softcap
+from repro.models.common import (apply_rope, client_mesh_axes, dense_init,
+                                 softcap)
 
 Array = jax.Array
 NEG_INF = -2.3819763e38  # max-negative bf16-representable
@@ -143,28 +144,11 @@ def _constrain_batch_only(x: Array, cfg: ModelConfig) -> Array:
     into the attention read and replicates the cache)."""
     if cfg.decode_cache_layout != 'batch':
         return x
-    try:
-        mesh = None
-        getam = getattr(jax.sharding, 'get_abstract_mesh', None)
-        if getam is not None:
-            am = getam()
-            if am is not None and am.axis_names:
-                mesh = am
-        if mesh is None:
-            from jax.interpreters import pxla
-            pm = pxla.thread_resources.env.physical_mesh
-            if pm is not None and pm.axis_names:
-                mesh = pm
-        if mesh is None:
-            return x
-        batch_axes = tuple(n for n in mesh.axis_names if n != 'model')
-        if not batch_axes:
-            return x
-        lead = batch_axes if len(batch_axes) > 1 else batch_axes[0]
-        spec = jax.sharding.PartitionSpec(lead, *([None] * (x.ndim - 1)))
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
+    lead = client_mesh_axes()
+    if lead is None:
         return x
+    spec = jax.sharding.PartitionSpec(lead, *([None] * (x.ndim - 1)))
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def attention_decode(params, cfg: ModelConfig, x: Array,

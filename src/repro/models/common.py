@@ -14,20 +14,12 @@ def dtype_of(name: str):
 
 
 def current_mesh_axes():
-    """Axis names of the ambient mesh, or None outside a mesh context."""
-    try:
-        getam = getattr(jax.sharding, 'get_abstract_mesh', None)
-        if getam is not None:
-            am = getam()
-            if am is not None and am.axis_names:
-                return tuple(am.axis_names), dict(am.shape)
-        from jax.interpreters import pxla
-        pm = pxla.thread_resources.env.physical_mesh
-        if pm is not None and pm.axis_names:
-            return tuple(pm.axis_names), dict(pm.shape)
-    except Exception:
-        pass
-    return None, None
+    """Axis names and sizes of the ambient mesh (``jax.set_mesh``), or
+    (None, None) outside a mesh context."""
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
+        return None, None
+    return tuple(am.axis_names), dict(am.shape)
 
 
 def maybe_constrain(x: Array, spec_entries) -> Array:
@@ -50,11 +42,8 @@ def maybe_constrain(x: Array, spec_entries) -> Array:
         for a in axes:
             total *= shape[a]
         out.append(entry if dim % total == 0 else None)
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, jax.sharding.PartitionSpec(*out))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*out))
 
 
 def client_mesh_axes():

@@ -54,7 +54,6 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.flatten_util import ravel_pytree
 
 from repro import adversary
@@ -333,7 +332,7 @@ class FLSimulator:
 
             # traced (and always re-entered) under x64: the closed forms
             # overflow f32 — see allocation_jax's precision contract
-            with enable_x64():
+            with jax.enable_x64():
                 self._alloc_jax = jax.jit(alloc_on_device)
 
     # ------------------------------------------------------------------
@@ -789,7 +788,7 @@ class FLSimulator:
                     if fl.allocation_backend == 'jax':
                         # one on-device dispatch, no host round-trip (the
                         # x64 re-entry keeps the jit cache key stable)
-                        with enable_x64():
+                        with jax.enable_x64():
                             (q, p, _, _, alloc_obj, alloc_iters,
                              alloc_reason) = self._alloc_jax(
                                 grads, self.gbar, gains_n, p_w_n)

@@ -20,9 +20,9 @@ Kernels:
 * ``unpack_dequant_kernel`` — the fused PS-side pass: unpack both packets
                               + knob reconstruction + compensation select
                               + 1/q weighting (eq. (15)-(17)) in one pass.
-* ``fold_words_kernel``     — per-client xor-fold of a (K, W) word
-                              buffer, accumulated across word-block grid
-                              steps: the on-chip form of the CRC
+* ``fold_words_kernel``     — per-client, lane-wise xor-fold of a (K, W)
+                              word buffer, accumulated across word-block
+                              grid steps: the on-chip form of the CRC
                               reduction (format.xor_fold).  Live in the
                               PS verify path of the bit-level transports
                               (repro.core.bitchannel) since the
@@ -53,18 +53,26 @@ Kernels:
                               thresholds them against the per-client BER,
                               packs the flip mask in-register, xors it
                               into the payload, and accumulates both the
-                              mask's xor-fold (fusing fold_words_kernel's
-                              reduction into the same pass) and its
-                              popcount — no (..., W, 32) random tensor
-                              ever exists.  Off-TPU the interpret-mode
-                              pallas_call doubles as a fusion boundary,
+                              mask's lane-wise xor-fold (fusing
+                              fold_words_kernel's reduction into the same
+                              pass) and its popcount — no (..., W, 32)
+                              random tensor ever exists.  Off-TPU the
+                              interpret-mode pallas_call doubles as a
+                              fusion boundary,
                               stopping XLA CPU from re-running the hash
                               chain once per downstream consumer.
 
-Per-client scalars travel as (1, 1) blocks exactly like
-``kernels.quantize_kernel``.  Everything is validated against the
+Per-launch scalars travel as (1, 1) blocks exactly like
+``kernels.quantize_kernel``; the decode-once kernel's per-client scalars
+sit whole in SMEM, indexed by the client grid step.  The kernels keep to
+what the TPU lowering accepts: unsigned lane sums run as int32 (the
+summed lanes hold disjoint bits), uint32 values reach f32 through an
+int32 view (they are < 2^bits), and the xor-folds stay lane-wide in the
+kernel, their last cross-lane xor done by the caller
+(``format.xor_fold``).  Everything is validated against the
 ``format``/``corrupt`` references in interpret mode (tests/test_wire.py,
-tests/test_bitchannel.py).
+tests/test_bitchannel.py) and compiled for a v5e chip at real widths
+(tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -73,6 +81,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.quantize_kernel import quantize_body
 from repro.wire.corrupt import hash_bits
@@ -100,12 +109,22 @@ def _lane(shape):
     return jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
 
 
+def _i32(x: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _u32(x: jax.Array) -> jax.Array:
+    return jax.lax.bitcast_convert_type(x, jnp.uint32)
+
+
 def _pack(v: jax.Array, bits: int) -> jax.Array:
-    """(BG, 32) uint32 -> (BG, bits) words."""
+    """(BG, 32) uint32 -> (BG, bits) words.  The lane reduction runs in
+    int32 (the TPU lowering has no unsigned reductions): each lane holds
+    a distinct bit, so the wrapping int32 sum equals the or."""
     lane = _lane(v.shape)
-    planes = [jnp.sum(((v >> j) & jnp.uint32(1)) << lane, axis=1,
-                      dtype=jnp.uint32) for j in range(bits)]
-    return jnp.stack(planes, axis=1)
+    planes = [jnp.sum(_i32(((v >> j) & jnp.uint32(1)) << lane), axis=1,
+                      dtype=jnp.int32) for j in range(bits)]
+    return _u32(jnp.stack(planes, axis=1))
 
 
 def _unpack(w: jax.Array, bits: int) -> jax.Array:
@@ -138,7 +157,7 @@ def quantize_pack_kernel(gmin_ref, gmax_ref, g_ref, r_ref,
     qidx = quantize_body(g, r_ref[...].astype(jnp.float32),
                          gmin_ref[0, 0], gmax_ref[0, 0], bits)
     sw_ref[...] = _pack((g >= 0.0).astype(jnp.uint32), 1)
-    qw_ref[...] = _pack(qidx.astype(jnp.uint32), bits)
+    qw_ref[...] = _pack(_u32(qidx.astype(jnp.int32)), bits)
 
 
 def _dequant_contrib(sw, qw, gbar, gmin, step, mod_ok, w, bits: int):
@@ -150,7 +169,8 @@ def _dequant_contrib(sw, qw, gbar, gmin, step, mod_ok, w, bits: int):
     tile (BG, 32) f32)."""
     sign_bits = _unpack(sw.astype(jnp.uint32), 1)
     sign = jnp.where(sign_bits > 0, 1.0, -1.0)
-    qidx = _unpack(qw.astype(jnp.uint32), bits).astype(jnp.float32)
+    # qidx < 2^bits: the int32 view is exact (no uint32 -> f32 cast on TPU)
+    qidx = _i32(_unpack(qw.astype(jnp.uint32), bits)).astype(jnp.float32)
     modulus = gmin + qidx * step
     modulus = jnp.where(mod_ok > 0.0, modulus, gbar.astype(jnp.float32))
     return sign_bits, w * (sign * modulus)
@@ -187,8 +207,8 @@ def spfl_accumulate_kernel(gmin_ref, step_ref, mod_ok_ref, weight_ref,
     """
     k = pl.program_id(1)
     sign_bits, contrib = _dequant_contrib(
-        sw_ref[...], qw_ref[...], gbar_ref[...], gmin_ref[0, 0],
-        step_ref[0, 0], mod_ok_ref[0, 0], weight_ref[0, 0], bits)
+        sw_ref[...], qw_ref[...], gbar_ref[...], gmin_ref[k],
+        step_ref[k], mod_ok_ref[k], weight_ref[k], bits)
 
     @pl.when(k == 0)
     def _init():
@@ -201,7 +221,7 @@ def spfl_accumulate_kernel(gmin_ref, step_ref, mod_ok_ref, weight_ref,
         acc_ref[...] = acc_ref[...] + contrib
 
     if with_votes:
-        voted = sign_bits * vote_gate_ref[0, 0]
+        voted = sign_bits * vote_gate_ref[k]
 
         @pl.when(k == 0)
         def _init_votes():
@@ -221,10 +241,10 @@ def corrupt_fold_kernel(seed_ref, off_ref, thresh_ref, allflip_ref, w_ref,
                         rx_ref, fold_ref, flips_ref, *, n_words: int):
     """Fused bit channel: counter-PRF draw -> threshold -> in-register
     pack -> xor into the payload, with the flip mask's xor-fold
-    (fold_words_kernel's reduction) and popcount accumulated in the same
-    pass.  ``n_words`` is the true (unpadded) buffer width: the global
-    word index matches the jnp reference exactly and padding columns
-    never flip.  ``off_ref`` is the buffer's word offset in the global
+    (fold_words_kernel's reduction) and popcount accumulated lane-wise in
+    the same pass.  ``n_words`` is the true (unpadded) buffer width: the
+    global word index matches the jnp reference exactly and padding
+    columns never flip.  ``off_ref`` is the buffer's word offset in the global
     counter stream (``first_row * n_words`` on a client-sharded slice —
     the sharded channel draws the same bits the gathered one would)."""
     j = pl.program_id(0)
@@ -244,36 +264,34 @@ def corrupt_fold_kernel(seed_ref, off_ref, thresh_ref, allflip_ref, w_ref,
         bit = (((h < thresh).astype(jnp.uint32) | allf) & valid)
         mask = mask | (bit << jnp.uint32(b))
     rx_ref[...] = words ^ mask
-    fold = jax.lax.reduce(mask, jnp.uint32(0), jax.lax.bitwise_xor,
-                          (1,))[:, None]
-    flips = jnp.sum(jax.lax.population_count(mask), axis=1,
-                    dtype=jnp.int32)[:, None]
+    flips = _i32(jax.lax.population_count(mask))
 
     @pl.when(j == 0)
     def _init():
-        fold_ref[...] = fold
+        fold_ref[...] = mask
         flips_ref[...] = flips
 
     @pl.when(j != 0)
     def _acc():
-        fold_ref[...] = fold_ref[...] ^ fold
+        fold_ref[...] = fold_ref[...] ^ mask
         flips_ref[...] = flips_ref[...] + flips
 
 
 def fold_words_kernel(w_ref, f_ref):
-    """Xor-fold one (K, BLOCK_FOLD_WORDS) block into the (K, 1)
-    accumulator; grid step 0 initializes, later steps accumulate (xor is
-    associative/commutative, so block order is irrelevant)."""
-    fold = jax.lax.reduce(w_ref[...].astype(jnp.uint32), jnp.uint32(0),
-                          jax.lax.bitwise_xor, (1,))[:, None]
+    """Xor one (K, BLOCK_FOLD_WORDS) block into the lane-wide (K,
+    BLOCK_FOLD_WORDS) accumulator; grid step 0 initializes, later steps
+    accumulate (xor is associative/commutative, so block order is
+    irrelevant).  The last cross-lane fold happens outside the kernel:
+    the TPU lowering has no xor reduction."""
+    w = w_ref[...].astype(jnp.uint32)
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        f_ref[...] = fold
+        f_ref[...] = w
 
     @pl.when(pl.program_id(0) != 0)
     def _acc():
-        f_ref[...] = f_ref[...] ^ fold
+        f_ref[...] = f_ref[...] ^ w
 
 
 # ---------------------------------------------------------------------------
@@ -334,15 +352,17 @@ def quantize_pack_2d(g, rand, gmin, gmax, *, bits: int,
 @functools.partial(jax.jit, static_argnames=('interpret',))
 def fold_words_2d(words, *, interpret: bool = False):
     """words: (K, W) uint32 with W a BLOCK_FOLD_WORDS multiple
-    -> (K, 1) per-client xor-fold."""
+    -> (K, BLOCK_FOLD_WORDS) lane-wise xor-folds (``format.xor_fold``
+    over the last axis finishes the per-client fold)."""
     k, w_n = words.shape
     assert w_n % BLOCK_FOLD_WORDS == 0, w_n
+    spec = pl.BlockSpec((k, BLOCK_FOLD_WORDS), lambda j: (0, j))
     return pl.pallas_call(
         fold_words_kernel,
         grid=(w_n // BLOCK_FOLD_WORDS,),
-        in_specs=[pl.BlockSpec((k, BLOCK_FOLD_WORDS), lambda j: (0, j))],
-        out_specs=pl.BlockSpec((k, 1), lambda j: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, 1), jnp.uint32),
+        in_specs=[spec],
+        out_specs=pl.BlockSpec((k, BLOCK_FOLD_WORDS), lambda j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, BLOCK_FOLD_WORDS), jnp.uint32),
         interpret=interpret,
     )(words)
 
@@ -358,15 +378,15 @@ def spfl_accumulate_2d(sign_words, qidx_words, gbar, gmin, step, mod_ok,
 
     sign_words (K*G_pad, 1) / qidx_words (K*G_pad, bits): every client's
     padded group-major payload stacked along rows; gbar (G_pad, 32)
-    shared or (K*G_pad, 32) per-client; per-client scalars (K, 1)
-    (vote_gate uint32 0/1 = sign_ok, ``step`` the precomputed knob step,
-    the rest f32).
+    shared or (K*G_pad, 32) per-client; per-client scalars (K,), whole
+    in SMEM and indexed by the client grid step (vote_gate uint32 0/1 =
+    sign_ok, ``step`` the precomputed knob step, the rest f32).
     -> (client-sum (G_pad, 32) f32, sign votes (G_pad, 32) uint32).
     """
     rows = sign_words.shape[0] // n_clients
     gb = rows // BLOCK_GROUPS            # group blocks per client
     assert gb * BLOCK_GROUPS == rows, (sign_words.shape, n_clients)
-    scal = pl.BlockSpec((1, 1), lambda i, k: (k, 0))
+    scal = pl.BlockSpec(memory_space=pltpu.SMEM)
     pay = lambda width: pl.BlockSpec((BLOCK_GROUPS, width),
                                      lambda i, k: (k * gb + i, 0))
     gbar_spec = pay(GROUP) if gbar_per_client else \
@@ -391,22 +411,27 @@ def corrupt_fold_2d(seeds, word0, thresh, allflip, words, *, n_words: int,
     BLOCK_CORRUPT_WORDS multiple; columns >= n_words never flip).
     seeds (1, 2) uint32; word0 (1, 1) uint32 global word offset;
     thresh/allflip (K, 1) uint32.
-    -> (received (K, W_pad), mask xor-fold (K, 1), flip count (K, 1))."""
+    -> (received (K, W_pad), lane-wise mask xor-folds (K,
+    BLOCK_CORRUPT_WORDS) uint32, lane-wise flip counts (K,
+    BLOCK_CORRUPT_WORDS) int32); the caller finishes both lane
+    reductions (the TPU lowering has no xor reduction)."""
     k, w_pad = words.shape
     assert w_pad % BLOCK_CORRUPT_WORDS == 0, w_pad
-    acc_spec = pl.BlockSpec((k, 1), lambda j: (0, 0))
+    flag_spec = pl.BlockSpec((k, 1), lambda j: (0, 0))
+    acc_spec = pl.BlockSpec((k, BLOCK_CORRUPT_WORDS), lambda j: (0, 0))
     return pl.pallas_call(
         functools.partial(corrupt_fold_kernel, n_words=n_words),
         grid=(w_pad // BLOCK_CORRUPT_WORDS,),
         in_specs=[pl.BlockSpec((1, 2), lambda j: (0, 0)),
                   pl.BlockSpec((1, 1), lambda j: (0, 0)),
-                  acc_spec, acc_spec,
+                  flag_spec, flag_spec,
                   pl.BlockSpec((k, BLOCK_CORRUPT_WORDS), lambda j: (0, j))],
         out_specs=[pl.BlockSpec((k, BLOCK_CORRUPT_WORDS), lambda j: (0, j)),
                    acc_spec, acc_spec],
         out_shape=[jax.ShapeDtypeStruct((k, w_pad), jnp.uint32),
-                   jax.ShapeDtypeStruct((k, 1), jnp.uint32),
-                   jax.ShapeDtypeStruct((k, 1), jnp.int32)],
+                   jax.ShapeDtypeStruct((k, BLOCK_CORRUPT_WORDS), jnp.uint32),
+                   jax.ShapeDtypeStruct((k, BLOCK_CORRUPT_WORDS),
+                                        jnp.int32)],
         interpret=interpret,
     )(seeds, word0, thresh, allflip, words)
 

@@ -25,7 +25,6 @@ import jax
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
-from jax.experimental import enable_x64
 
 from repro.configs.base import FLConfig
 from repro.core import allocation as AL
@@ -110,11 +109,11 @@ def test_vmap_batch_bit_matches_single_solves(method):
     probs = [_problem(k=6, power_dbm=p, seed=s)
              for s, p in enumerate([-4.0, -10.0, -16.0, -22.0, -28.0,
                                     -34.0, -8.0, -19.0])]
-    with enable_x64():
+    with jax.enable_x64():
         batched = AJ.stack_problems(probs)
     sol = AJ.solve_batched(batched, method, max_iters=3)
     for i, prob in enumerate(probs):
-        with enable_x64():
+        with jax.enable_x64():
             one = AJ._solve_jit(AJ.from_reference(prob), method=method,
                                 max_iters=3)
         for f in ('alpha', 'beta', 'q', 'p', 'objective', 'iters'):
@@ -125,7 +124,7 @@ def test_vmap_batch_bit_matches_single_solves(method):
 
 def test_batch_over_gains_shapes():
     prob = _problem(k=4, power_dbm=-20.0, seed=9)
-    with enable_x64():
+    with jax.enable_x64():
         jp = AJ.from_reference(prob)
         fades = CH.block_fading_trajectory(jax.random.PRNGKey(0),
                                            prob.gains, 12)
@@ -143,13 +142,13 @@ def test_batched_solve_matches_numpy_reference_over_64_fading_draws():
     matches the NumPy reference per-draw within the documented
     tolerance."""
     base = _problem(k=8, power_dbm=-16.0, seed=2)
-    with enable_x64():
+    with jax.enable_x64():
         fades = CH.block_fading_trajectory(jax.random.PRNGKey(7),
                                            base.gains, 64, rho=0.8,
                                            shadow_std_db=4.0)
     fades = np.asarray(fades, np.float64)
     probs = [dataclasses.replace(base, gains=fades[i]) for i in range(64)]
-    with enable_x64():
+    with jax.enable_x64():
         sol = AJ.solve_batched(AJ.stack_problems(probs), 'barrier')
     for i, prob in enumerate(probs):
         ref = AL.solve(prob, 'barrier')
@@ -337,11 +336,11 @@ def test_vmap_batch_early_exit_bit_matches_single_solves(method):
     finished ones — still bit-identical to single early-exit solves."""
     probs = [_problem(k=6, power_dbm=p, seed=s)
              for s, p in enumerate([-4.0, -16.0, -28.0, -8.0])]
-    with enable_x64():
+    with jax.enable_x64():
         batched = AJ.stack_problems(probs)
     sol = AJ.solve_batched(batched, method, max_iters=3, early_exit=True)
     for i, prob in enumerate(probs):
-        with enable_x64():
+        with jax.enable_x64():
             one = AJ._solve_jit(AJ.from_reference(prob), method=method,
                                 max_iters=3, early_exit=True)
         for f in ('alpha', 'beta', 'q', 'p', 'objective', 'iters',
@@ -358,7 +357,7 @@ def test_ragged_stack_padded_solve_matches_unpadded(method):
     clients' solution is bit-identical to the unpadded single solve."""
     probs = [_problem(k=4, power_dbm=-10.0, seed=21),
              _problem(k=8, power_dbm=-22.0, seed=22)]
-    with enable_x64():
+    with jax.enable_x64():
         batched = AJ.stack_problems(probs)
     assert batched.mask is not None and batched.A.shape == (2, 8)
     np.testing.assert_array_equal(

@@ -28,6 +28,7 @@ import pytest
 from repro.configs.base import FLConfig
 from repro.core import transport as TR
 from repro.kernels import ops, ref
+from repro.launch.mesh import make_mesh
 from repro.wire import format as fmt
 
 ON_MESH = jax.device_count() >= 8
@@ -63,12 +64,12 @@ def test_grid_on_forced_8_device_mesh():
 
 @pytest.fixture(scope='module')
 def mesh():
-    return jax.make_mesh((8,), ('data',))
+    return make_mesh((8,), ('data',))
 
 
 @pytest.fixture(scope='module')
 def pod_mesh():
-    return jax.make_mesh((2, 4), ('pod', 'data'))
+    return make_mesh((2, 4), ('pod', 'data'))
 
 
 def _payloads(k, n, bits, seed=0):
@@ -86,12 +87,6 @@ def _payloads(k, n, bits, seed=0):
     )
     gbar = jnp.asarray(rng.uniform(0.0, 1.0, n), jnp.float32)
     return sign, sw, qw, gbar, scal
-
-
-def _ulp_atol(weight, gmax, gbar):
-    scale = float(jnp.sum(jnp.asarray(weight)
-                          * jnp.maximum(jnp.asarray(gmax), jnp.max(gbar))))
-    return 4 * np.finfo(np.float32).eps * max(scale, 1.0)
 
 
 def _grads(k, l, seed):
@@ -128,7 +123,7 @@ def test_sharded_matches_gathered_grid(mesh, k, n, bits):
         s['sign_ok'], n, bits)
     np.testing.assert_allclose(
         np.asarray(acc_s), np.asarray(racc), rtol=0,
-        atol=_ulp_atol(s['weight'], s['gmax'], gbar))
+        atol=ops.aggregate_atol(s['weight'], s['gmax'], gbar))
     # votes: bit-exact vs the sequential reference — and per-shard vote
     # words lift the capacity to 32 clients/shard, so K=33 still votes
     # (the gathered kernel returns None there)
@@ -141,7 +136,7 @@ def test_sharded_matches_gathered_grid(mesh, k, n, bits):
         assert jnp.array_equal(v_s, v_g)
     np.testing.assert_allclose(
         np.asarray(acc_s), np.asarray(acc_g), rtol=0,
-        atol=_ulp_atol(s['weight'], s['gmax'], gbar))
+        atol=ops.aggregate_atol(s['weight'], s['gmax'], gbar))
 
 
 @needs_mesh
@@ -158,7 +153,7 @@ def test_sharded_per_client_gbar_and_pod_mesh(pod_mesh):
         s['sign_ok'], n, bits)
     np.testing.assert_allclose(
         np.asarray(acc_s), np.asarray(racc), rtol=0,
-        atol=_ulp_atol(s['weight'], s['gmax'], gbar_k))
+        atol=ops.aggregate_atol(s['weight'], s['gmax'], gbar_k))
 
 
 @needs_mesh
@@ -206,7 +201,7 @@ def test_flat_transport_sharded_matches_gathered(mesh, channel, n_retx):
     gmax = jnp.max(jnp.abs(grads), axis=1)
     np.testing.assert_allclose(
         np.asarray(gh_g), np.asarray(gh_s), rtol=0,
-        atol=_ulp_atol(w, gmax, gbar) / k)
+        atol=ops.aggregate_atol(w, gmax, gbar) / k)
 
 
 @needs_mesh
@@ -228,7 +223,7 @@ def test_flat_sharded_under_jit_with_sharded_inputs(mesh):
     gmax = jnp.max(jnp.abs(grads), axis=1)
     np.testing.assert_allclose(
         np.asarray(gh_g), np.asarray(gh_s), rtol=0,
-        atol=_ulp_atol(w, gmax, gbar) / k)
+        atol=ops.aggregate_atol(w, gmax, gbar) / k)
 
 
 @needs_mesh
@@ -253,7 +248,7 @@ def test_tree_transport_sharded_matches_gathered(mesh, channel):
     for leaf in out_g:
         np.testing.assert_allclose(
             np.asarray(out_g[leaf]), np.asarray(out_s[leaf]), rtol=0,
-            atol=_ulp_atol(w, gmax, gbar) / k)
+            atol=ops.aggregate_atol(w, gmax, gbar) / k)
 
 
 @needs_mesh
@@ -269,7 +264,7 @@ def test_error_free_sharded_matches_gathered(mesh):
     gmax = jnp.max(jnp.abs(grads), axis=1)
     np.testing.assert_allclose(
         np.asarray(gh_g), np.asarray(gh_s), rtol=0,
-        atol=_ulp_atol(jnp.ones(k), gmax, jnp.zeros(1)) / k)
+        atol=ops.aggregate_atol(jnp.ones(k), gmax, jnp.zeros(1)) / k)
     tree = {'a': grads[:, :512], 'b': grads[:, 512:]}
     t_g, _, _ = TR.error_free_aggregate_tree(tree, fl, key)
     t_s, _, _ = TR.error_free_aggregate_tree(tree, fl, key,
@@ -278,7 +273,7 @@ def test_error_free_sharded_matches_gathered(mesh):
     for leaf in t_g:
         np.testing.assert_allclose(
             np.asarray(t_g[leaf]), np.asarray(t_s[leaf]), rtol=0,
-            atol=_ulp_atol(jnp.ones(k), gmax, jnp.zeros(1)) / k)
+            atol=ops.aggregate_atol(jnp.ones(k), gmax, jnp.zeros(1)) / k)
 
 
 @needs_mesh
@@ -328,6 +323,12 @@ def test_sharded_requires_packed_wire_and_mesh():
         TR.spfl_aggregate(grads, gbar, ones, ones, 3, 64,
                           jax.random.PRNGKey(0), wire='packed',
                           collective='sharded')
+    # jax.make_mesh's default Explicit axes cannot carry the GSPMD program
+    with pytest.raises(ValueError, match='Auto-typed'):
+        TR.spfl_aggregate(grads, gbar, ones, ones, 3, 64,
+                          jax.random.PRNGKey(0), wire='packed',
+                          collective='sharded',
+                          mesh=jax.make_mesh((1,), ('data',)))
     with pytest.raises(ValueError, match='mesh'):
         from repro.configs.registry import get_arch
         from repro.training import distributed as D

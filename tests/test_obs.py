@@ -23,6 +23,7 @@ import pytest
 
 from repro.configs.base import FLConfig
 from repro.core import transport as TR
+from repro.launch.mesh import make_host_mesh
 from repro.obs import (
     SCALAR_KEYS, JsonlSink, MetricsRegistry, ReservoirHistogram,
     RoundTelemetry, config_hash, read_jsonl, ring_init, ring_push,
@@ -236,7 +237,7 @@ def test_zero_transfers_screening_sharded():
     """Sharded collective + screening under the device->host guard —
     the global-view vote/z-score stays a GSPMD computation."""
     from repro import adversary as adv
-    mesh = jax.make_mesh((jax.device_count(),), ('data',))
+    mesh = make_host_mesh()
     key = jax.random.PRNGKey(1)
     common = jax.random.normal(key, (L,))
     grads = (common[None, :]
@@ -299,7 +300,7 @@ def test_jsonl_round_trip(tmp_path, wire, channel, collective):
                              channel=channel, collective=collective)
     mesh = None
     if collective == 'sharded':
-        mesh = jax.make_mesh((jax.device_count(),), ('data',))
+        mesh = make_host_mesh()
     key = jax.random.PRNGKey(3)
     grads = jax.random.normal(key, (K, L)) * 0.01
     gbar = jnp.abs(jax.random.normal(jax.random.fold_in(key, 1), (L,)))
@@ -391,8 +392,10 @@ def test_reservoir_histogram_deterministic():
 # run manifest / launch.env
 # ---------------------------------------------------------------------------
 
-def test_manifest_records_env_state():
+def test_manifest_records_env_state(monkeypatch, tmp_path):
     from repro.launch import env as launch_env
+    # an outside cache directory leaves jax's in-process cache untouched
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     launch_env.configure()
     man = run_manifest(FLConfig())
     assert man['env']['configured'] is True

@@ -7,7 +7,7 @@ Exactness contract (see repro.core.transport.__doc__):
   flip counts — is bit-exact everywhere;
 * the f32 reconstruction of the decode-once kernel agrees with the jnp
   references to within a couple of ulp (the compiler FMA-contracts the
-  kernel's fused mul+add chains), pinned by ``_ulp_atol``.
+  kernel's fused mul+add chains), pinned by ``ops.aggregate_atol``.
 """
 import dataclasses
 
@@ -25,15 +25,6 @@ from repro.wire import format as fmt
 from repro.wire import packets
 
 FL = FLConfig()
-
-
-def _ulp_atol(weight, gmax, gbar):
-    """FMA-wobble bound: a couple of ulp per client contribution,
-    accumulated — 4 eps x sum_k w_k max(gmax_k, max gbar).  Real decode
-    bugs land at the knob-step scale, orders of magnitude above."""
-    scale = float(jnp.sum(jnp.asarray(weight)
-                          * jnp.maximum(jnp.asarray(gmax), jnp.max(gbar))))
-    return 4 * np.finfo(np.float32).eps * max(scale, 1.0)
 
 
 def _payloads(k, n, bits, seed=0):
@@ -73,7 +64,7 @@ def test_decode_once_matches_reference_grid(n, bits, k, use_kernel):
         s['sign_ok'], n, bits)
     np.testing.assert_allclose(
         np.asarray(acc), np.asarray(racc), rtol=0,
-        atol=_ulp_atol(s['weight'], s['gmax'], gbar))
+        atol=ops.aggregate_atol(s['weight'], s['gmax'], gbar))
     assert jnp.array_equal(votes, rvotes)            # integers: bit-exact
     # votes are the per-coordinate +1 count among accepted clients
     expect = jnp.sum((sign > 0) & s['sign_ok'][:, None], axis=0)
@@ -93,7 +84,7 @@ def test_decode_once_per_client_gbar():
         s['sign_ok'], n, bits)
     np.testing.assert_allclose(
         np.asarray(acc), np.asarray(racc), rtol=0,
-        atol=_ulp_atol(s['weight'], s['gmax'], gbar_k))
+        atol=ops.aggregate_atol(s['weight'], s['gmax'], gbar_k))
 
 
 def test_decode_once_votes_capacity():
@@ -109,7 +100,7 @@ def test_decode_once_votes_capacity():
             s['sign_ok'], 200, 3)
         np.testing.assert_allclose(
             np.asarray(acc), np.asarray(racc), rtol=0,
-            atol=_ulp_atol(s['weight'], s['gmax'], gbar))
+            atol=ops.aggregate_atol(s['weight'], s['gmax'], gbar))
         if present:
             assert jnp.array_equal(votes, rvotes)
         else:
