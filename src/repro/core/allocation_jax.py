@@ -105,6 +105,9 @@ class JaxAllocation(NamedTuple):
     objectives: jax.Array        # (..., max_iters) per-outer-iter objective
                                  # trajectory (NaN beyond ``iters``)
     exit_reason: jax.Array       # (...,)  int32 EXIT_* code
+    steps: jax.Array             # (...,)  int32 trips of the innermost
+    #   serial loops (see ``solve_traceable``): the solve's work, where
+    #   ``iters`` counts only its outer iterations
 
 
 class _Caps(NamedTuple):
@@ -250,7 +253,7 @@ def _msum(prob, x):
     return _ordered_sum(x if prob.mask is None else x * prob.mask)
 
 
-def _bounded_fori(n, body, init, stop, early_exit):
+def _bounded_fori(n, body, init, stop, early_exit, freezes=True):
     """``lax.fori_loop(0, n, body, init)`` with a convergence exit.
 
     ``stop(carry) -> bool[]`` reads the loop's own ``done`` flag; when
@@ -263,9 +266,26 @@ def _bounded_fori(n, body, init, stop, early_exit):
     masked all-converged predicate, for free.  The hard ``i < n`` bound
     keeps the loop compilable inside ``lax.scan`` (the fused f32
     in-round path).
+
+    Returns ``(carry, trips)``: the trips taken before ``stop`` fired —
+    the while form's own ``i``.  The fixed-trip form counts the trips
+    it entered with ``stop`` still false (the later ones are frozen
+    no-ops), so both lowerings report the same count.  A body that does
+    not freeze (``freezes=False``: the golden-section and bisection
+    searches, whose ``stop`` is only an exit tolerance) works on every
+    fixed trip, which then counts as the static ``n`` and adds no op.
     """
     if not early_exit:
-        return lax.fori_loop(0, n, body, init)
+        if not freezes:
+            return lax.fori_loop(0, n, body, init), n
+
+        def counted(i, tc):
+            trips, carry = tc
+            return (trips + (~stop(carry)).astype(jnp.int32),
+                    body(i, carry))
+
+        trips, carry = lax.fori_loop(0, n, counted, (jnp.int32(0), init))
+        return carry, trips
 
     def cond(ic):
         i, carry = ic
@@ -275,7 +295,8 @@ def _bounded_fori(n, body, init, stop, early_exit):
         i, carry = ic
         return i + 1, body(i, carry)
 
-    return lax.while_loop(cond, wbody, (jnp.int32(0), init))[1]
+    trips, carry = lax.while_loop(cond, wbody, (jnp.int32(0), init))
+    return carry, trips
 
 
 def _cs(prob):
@@ -405,7 +426,12 @@ def _surrogate(prob, caps, alpha, beta0):
     return surrogate
 
 
-def _golden_vec(f, shape, dtype, iters: int = 48,
+GOLDEN_ITERS = 48       # golden-section steps of one full search
+BISECTION_ITERS = 60    # dual bisection steps on the sum constraint
+BACKTRACK_ITERS = 27    # feasibility halvings of one barrier descent step
+
+
+def _golden_vec(f, shape, dtype, iters: int = GOLDEN_ITERS,
                 early_exit: bool = True, width_tol: float = 0.0):
     """Golden section on [BETA_MIN, BETA_MAX], elementwise.
 
@@ -413,7 +439,9 @@ def _golden_vec(f, shape, dtype, iters: int = 48,
     than it (tolerance-bounded exit: the returned midpoint is within
     ``width_tol/2`` of the fixed-trip one); ``width_tol=0`` runs the
     full fixed-trip schedule bit-identically (the interval never
-    reaches exact zero width, so the predicate only trips the cap)."""
+    reaches exact zero width, so the predicate only trips the cap).
+    Returns ``(midpoint, steps)``; ``steps`` is the static ``iters``
+    unless the tolerance exit is on."""
     gr = (np.sqrt(5.0) - 1.0) / 2.0
     lo = jnp.full(shape, AC.BETA_MIN, dtype)
     hi = jnp.full(shape, AC.BETA_MAX, dtype)
@@ -432,21 +460,30 @@ def _golden_vec(f, shape, dtype, iters: int = 48,
     def stop(carry):
         return jnp.max(carry[1] - carry[0]) <= width_tol
 
-    carry = _bounded_fori(iters, body, (lo, hi, c, d, f(c), f(d)),
-                          stop, early_exit and width_tol > 0.0)
-    return 0.5 * (carry[0] + carry[1])
+    carry, steps = _bounded_fori(iters, body, (lo, hi, c, d, f(c), f(d)),
+                                 stop, early_exit and width_tol > 0.0,
+                                 freezes=False)
+    return 0.5 * (carry[0] + carry[1]), steps
 
 
 def optimize_beta_sca(prob: JaxAllocationProblem, alpha, beta0,
                       sca_rounds: int = 8, tol: float = 1e-6,
                       caps: _Caps = None, early_exit: bool = True,
                       inner_tol: float = 0.0):
+    """-> ``(beta, steps)``: ``steps`` sums the golden-section steps of
+    every search the SCA rounds ran — the free search, and when the sum
+    constraint binds, one per dual bracket growth and bisection step
+    plus the final one."""
     caps = caps or _caps(prob.A.dtype)
     dtype = beta0.dtype
     shape = beta0.shape
+    # only the tolerance exit makes a search's step count vary; without
+    # it the count is GOLDEN_ITERS per search, multiplied out once per
+    # SCA round, so the grow and bisection bodies carry it unchanged
+    tally = early_exit and inner_tol > 0.0
 
     def sca_body(_, carry):
-        beta, prev, done = carry
+        beta, prev, done, steps = carry
         surrogate = _surrogate(prob, caps, alpha, beta)
 
         def beta_of_lambda(lam):
@@ -454,55 +491,66 @@ def optimize_beta_sca(prob: JaxAllocationProblem, alpha, beta0,
                                dtype, early_exit=early_exit,
                                width_tol=inner_tol)
 
-        b0 = beta_of_lambda(jnp.asarray(0.0, dtype))
+        b0, steps0 = beta_of_lambda(jnp.asarray(0.0, dtype))
 
         def dual(_):
             # grow the dual upper bracket (×10 from 1.0; 30 steps reach
             # the reference's 1e30 stop); once `need` clears, further
             # trips are frozen no-ops — the while form exits there
             def grow(_, carry):
-                hi, cont = carry
-                need = (cont & (_msum(prob, beta_of_lambda(hi)) > 1.0)
-                        & (hi < 1e30))
-                return jnp.where(need, hi * 10.0, hi), need
+                hi, cont, n = carry
+                b, t = beta_of_lambda(hi)
+                need = cont & (_msum(prob, b) > 1.0) & (hi < 1e30)
+                return (jnp.where(need, hi * 10.0, hi), need,
+                        n + t if tally else n)
 
-            hi, _ = _bounded_fori(
-                30, grow, (jnp.asarray(1.0, dtype), jnp.asarray(True)),
+            (hi, _, n_grow), grows = _bounded_fori(
+                30, grow, (jnp.asarray(1.0, dtype), jnp.asarray(True),
+                           jnp.int32(0)),
                 lambda c: ~c[1], early_exit)
 
             # ... then 60 bisection steps on the sum constraint
             # (``inner_tol`` stops once the dual bracket is relatively
             # that narrow — the fixed-trip schedule reaches 2^-60)
-            def bis(_, lh):
-                lo, hi = lh
+            def bis(_, lhn):
+                lo, hi, n = lhn
                 mid = 0.5 * (lo + hi)
-                infeas = _msum(prob, beta_of_lambda(mid)) > 1.0
-                return jnp.where(infeas, mid, lo), jnp.where(infeas, hi, mid)
+                b, t = beta_of_lambda(mid)
+                infeas = _msum(prob, b) > 1.0
+                return (jnp.where(infeas, mid, lo),
+                        jnp.where(infeas, hi, mid), n + t if tally else n)
 
-            def bis_stop(lh):
-                return (lh[1] - lh[0]) <= inner_tol * lh[1]
+            def bis_stop(lhn):
+                return (lhn[1] - lhn[0]) <= inner_tol * lhn[1]
 
-            _, hi = _bounded_fori(60, bis, (jnp.asarray(0.0, dtype), hi),
-                                  bis_stop,
-                                  early_exit and inner_tol > 0.0)
-            b = beta_of_lambda(hi)
+            (_, hi, n_bis), bisections = _bounded_fori(
+                BISECTION_ITERS, bis,
+                (jnp.asarray(0.0, dtype), hi, jnp.int32(0)), bis_stop,
+                early_exit and inner_tol > 0.0, freezes=False)
+            b, t = beta_of_lambda(hi)
+            searched = (n_grow + n_bis + t if tally
+                        else GOLDEN_ITERS * (grows + bisections + 1))
             return b * jnp.minimum(1.0, 1.0 / jnp.maximum(
-                _msum(prob, b), 1e-12))
+                _msum(prob, b), 1e-12)), jnp.asarray(searched, jnp.int32)
 
-        b = lax.cond(_msum(prob, b0) > 1.0, dual, lambda _: b0, None)
+        b, steps_dual = lax.cond(
+            _msum(prob, b0) > 1.0, dual,
+            lambda _: (b0, jnp.int32(0)), None)
         # MM guarantee: only accept descent on the true objective
         cur = _objective(prob, caps, alpha, b)
         accept = (cur <= prev) & ~done
         conv = jnp.abs(prev - cur) <= tol * (1.0 + jnp.abs(prev))
         beta2 = jnp.where(accept, b, beta)
         prev2 = jnp.where(done, prev, jnp.minimum(prev, cur))
-        return beta2, prev2, done | conv
+        steps2 = jnp.where(done, steps, steps + steps0 + steps_dual)
+        return beta2, prev2, done | conv, steps2
 
     prev0 = _objective(prob, caps, alpha, beta0)
-    beta, _, _ = _bounded_fori(sca_rounds, sca_body,
-                               (beta0, prev0, jnp.asarray(False)),
-                               lambda c: c[2], early_exit)
-    return beta
+    (beta, _, _, steps), _ = _bounded_fori(
+        sca_rounds, sca_body,
+        (beta0, prev0, jnp.asarray(False), jnp.int32(0)),
+        lambda c: c[2], early_exit)
+    return beta, steps
 
 
 # ---------------------------------------------------------------------------
@@ -515,6 +563,9 @@ def optimize_beta_barrier(prob: JaxAllocationProblem, alpha, beta0,
                           lr: float = 1e-3, caps: _Caps = None,
                           early_exit: bool = True,
                           inner_tol: float = 0.0):
+    """-> ``(beta, steps)``: ``steps`` counts the inner descent steps
+    of every barrier level, each with its ``BACKTRACK_ITERS``
+    feasibility halvings."""
     caps = caps or _caps(prob.A.dtype)
     dtype = beta0.dtype
     beta = jnp.maximum(beta0, 1e-4)
@@ -530,7 +581,8 @@ def optimize_beta_barrier(prob: JaxAllocationProblem, alpha, beta0,
                           _h_v(prob, caps, b), _h_s_prime(prob, caps, b),
                           _h_v_prime(prob, caps, b), exp_cap=caps.exp_cap)
 
-    def outer_body(oi, beta):
+    def outer_body(oi, carry):
+        beta, steps = carry
         mu = jnp.asarray(mu0, dtype) * jnp.asarray(mu_growth, dtype) ** oi
 
         def inner_body(_, carry):
@@ -555,8 +607,9 @@ def optimize_beta_barrier(prob: JaxAllocationProblem, alpha, beta0,
                 new2 = jnp.where(cont, beta - t2 * step * grad, new)
                 return t2, new2
 
-            t, new = lax.fori_loop(0, 27, back, (jnp.asarray(1.0, dtype),
-                                                 beta - step * grad))
+            t, new = lax.fori_loop(0, BACKTRACK_ITERS, back,
+                                   (jnp.asarray(1.0, dtype),
+                                    beta - step * grad))
             give_up = (gn < 1e-14) | (t <= 1e-8)
             # displacement criterion for the ~28k-step descent: once the
             # backtracked move falls below ``inner_tol`` the iterate has
@@ -568,12 +621,12 @@ def optimize_beta_barrier(prob: JaxAllocationProblem, alpha, beta0,
             beta2 = jnp.where(~done & ~give_up, new, beta)
             return beta2, done | give_up | stalled
 
-        beta, _ = _bounded_fori(inner, inner_body,
-                                (beta, jnp.asarray(False)),
-                                lambda c: c[1], early_exit)
-        return beta
+        (beta, _), descents = _bounded_fori(inner, inner_body,
+                                            (beta, jnp.asarray(False)),
+                                            lambda c: c[1], early_exit)
+        return beta, steps + descents * (1 + BACKTRACK_ITERS)
 
-    return lax.fori_loop(0, outer, outer_body, beta)
+    return lax.fori_loop(0, outer, outer_body, (beta, jnp.int32(0)))
 
 
 # ---------------------------------------------------------------------------
@@ -595,6 +648,14 @@ def solve_traceable(prob: JaxAllocationProblem, method: str = 'alternating',
     additionally unlocks tolerance-bounded exits of the golden-section /
     dual-bisection / barrier inner loops (see core/README.md for the
     accuracy contract); 0 keeps those loops reference-faithful.
+
+    ``steps`` counts the trips of the innermost serial loops of the
+    iterations that ran: for ``alternating`` every golden-section step
+    of every search, for ``barrier`` every descent step with its
+    backtracking halvings.  Alpha's fixed Newton polish is left out;
+    ``uniform`` counts 0.  At ``inner_tol=0`` the count is the same under
+    either lowering; with a tolerance exit the fixed-trip form of
+    ``alternating`` counts every search's and bisection's full schedule.
     """
     caps = _caps(prob.A.dtype)
     dtype = prob.A.dtype
@@ -610,22 +671,23 @@ def solve_traceable(prob: JaxAllocationProblem, method: str = 'alternating',
         return JaxAllocation(alpha_u, beta_u, q, p,
                              _objective(prob, caps, alpha_u, beta_u),
                              jnp.int32(0), nan_objs,
-                             jnp.int32(EXIT_CONVERGED))
+                             jnp.int32(EXIT_CONVERGED), jnp.int32(0))
 
     uniform_obj = _objective(prob, caps, alpha_u, beta_u)
     use_barrier = method == 'barrier'
 
     def body(i, carry):
-        alpha, beta, prev, done, bad_seen, iters, objs = carry
+        alpha, beta, prev, done, bad_seen, iters, objs, steps = carry
         alpha_n = optimize_alpha(prob, beta, n_grid, newton_iters, caps)
         if use_barrier:
-            beta_n = optimize_beta_barrier(prob, alpha_n, beta, caps=caps,
-                                           early_exit=early_exit,
-                                           inner_tol=inner_tol)
+            beta_n, steps_n = optimize_beta_barrier(
+                prob, alpha_n, beta, caps=caps, early_exit=early_exit,
+                inner_tol=inner_tol)
         else:
-            beta_n = optimize_beta_sca(prob, alpha_n, beta, caps=caps,
-                                       early_exit=early_exit,
-                                       inner_tol=inner_tol)
+            beta_n, steps_n = optimize_beta_sca(prob, alpha_n, beta,
+                                                caps=caps,
+                                                early_exit=early_exit,
+                                                inner_tol=inner_tol)
         obj = _objective(prob, caps, alpha_n, beta_n)
         # a non-finite iterate (f32 saturation) must not poison the
         # carry: freeze on the last good point instead of accepting it
@@ -637,14 +699,15 @@ def solve_traceable(prob: JaxAllocationProblem, method: str = 'alternating',
         prev2 = jnp.where(keep, prev, obj)
         iters2 = jnp.where(keep, iters, i + 1)
         objs2 = objs.at[i].set(jnp.where(keep, jnp.nan, obj))
+        steps2 = jnp.where(done, steps, steps + steps_n)
         return (alpha2, beta2, prev2, done | conv | bad,
-                bad_seen | (bad & ~done), iters2, objs2)
+                bad_seen | (bad & ~done), iters2, objs2, steps2)
 
     init = (alpha_u, beta_u, jnp.asarray(jnp.inf, dtype),
             jnp.asarray(False), jnp.asarray(False), jnp.int32(0),
-            nan_objs)
-    alpha, beta, prev, done, bad_seen, iters, objs = _bounded_fori(
-        max_iters, body, init, lambda c: c[3], early_exit)
+            nan_objs, jnp.int32(0))
+    (alpha, beta, prev, done, bad_seen, iters, objs, steps), _ = (
+        _bounded_fori(max_iters, body, init, lambda c: c[3], early_exit))
     # safeguard: never return anything worse than the uniform default.
     # Written NaN-proof (~(prev <= uniform)) so a non-finite objective
     # falls back to uniform instead of escaping the comparison
@@ -658,7 +721,8 @@ def solve_traceable(prob: JaxAllocationProblem, method: str = 'alternating',
                   jnp.where(done, jnp.int32(EXIT_CONVERGED),
                             jnp.int32(EXIT_ITER_CAP))))
     q, p = success_probs(prob, alpha, beta)
-    return JaxAllocation(alpha, beta, q, p, prev, iters, objs, reason)
+    return JaxAllocation(alpha, beta, q, p, prev, iters, objs, reason,
+                         steps)
 
 
 _STATIC = ('method', 'max_iters', 'tol', 'n_grid', 'newton_iters',
@@ -724,7 +788,8 @@ def solve(prob, method: str = 'alternating', max_iters: int = 6,
     """Drop-in for ``allocation.solve``: accepts the NumPy reference
     problem (or a pre-built pytree), solves on-device under x64, returns
     the host :class:`Allocation` with ``info['iters_used']`` /
-    ``info['exit_reason']`` reporting the solver effort."""
+    ``info['exit_reason']`` / ``info['steps']`` reporting the solver
+    effort."""
     with jax.enable_x64():
         jp = from_reference(prob) if isinstance(prob, AllocationProblem) \
             else prob
@@ -739,6 +804,7 @@ def solve(prob, method: str = 'alternating', max_iters: int = 6,
                       float(sol.objective),
                       {'iters': iters_used, 'iters_used': iters_used,
                        'exit_reason': int(sol.exit_reason),
+                       'steps': int(sol.steps),
                        'method': method, 'backend': 'jax',
                        'objectives': [float(o) for o in
                                       objs[~np.isnan(objs)]]})

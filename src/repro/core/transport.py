@@ -744,64 +744,67 @@ def spfl_aggregate_tree(grads_tree, gbar_tree, q: Array, p: Array,
     keys = jax.random.split(kq, len(leaves))
 
     # ---- clients: quantize every leaf (+ pack on the packed wire) ----
-    qgs, sws, qws = [], [], []
-    payload_words = 0
-    for lf, lkey in zip(leaves, keys):
-        Kd = lf.shape[0]
-        flat = lf.astype(jnp.float32).reshape(Kd, -1)
-        qg = stochastic_quantize(flat, bits, lkey,
-                                 g_min[:, None], g_max[:, None])
-        if attack == 'signflip' and byz is not None:
-            qg = adv_clients.flip_signs(qg, byz)
-        if attack == 'scaled' and byz is not None:
-            # the analytic dequant must see the scaled *report*
-            qg = qg._replace(g_min=g_min_rep[:, None],
-                             g_max=g_max_rep[:, None])
-        qgs.append(qg)
-        if wire == 'packed':
-            sw = wire_fmt.pack_bits_ref(wire_fmt.sign_to_bits(qg.sign), 1)
-            qw = wire_fmt.pack_bits_ref(qg.qidx, bits)
-            if sharded:
-                sw = _client_constrain(sw, mesh, client_axes)
-                qw = _client_constrain(qw, mesh, client_axes)
-            sws.append(sw)
-            qws.append(qw)
-            payload_words += sws[-1].shape[-1] + qws[-1].shape[-1]
+    with stage_scope('quantize_pack'):
+        qgs, sws, qws = [], [], []
+        payload_words = 0
+        for lf, lkey in zip(leaves, keys):
+            Kd = lf.shape[0]
+            flat = lf.astype(jnp.float32).reshape(Kd, -1)
+            qg = stochastic_quantize(flat, bits, lkey,
+                                     g_min[:, None], g_max[:, None])
+            if attack == 'signflip' and byz is not None:
+                qg = adv_clients.flip_signs(qg, byz)
+            if attack == 'scaled' and byz is not None:
+                # the analytic dequant must see the scaled *report*
+                qg = qg._replace(g_min=g_min_rep[:, None],
+                                 g_max=g_max_rep[:, None])
+            qgs.append(qg)
+            if wire == 'packed':
+                sw = wire_fmt.pack_bits_ref(wire_fmt.sign_to_bits(qg.sign), 1)
+                qw = wire_fmt.pack_bits_ref(qg.qidx, bits)
+                if sharded:
+                    sw = _client_constrain(sw, mesh, client_axes)
+                    qw = _client_constrain(qw, mesh, client_axes)
+                sws.append(sw)
+                qws.append(qw)
+                payload_words += sws[-1].shape[-1] + qws[-1].shape[-1]
 
     # ---- channel: packet fate (and, bit-level, payload damage) ----
     extras = {}
     shard_kw = dict(mesh=mesh if sharded else None,
                     client_axes=client_axes)
     if channel == 'bitlevel':
-        sign_frame = wire_fmt.SIGN_HEADER_WORDS + wire_fmt.CRC_WORDS
-        mod_frame = wire_fmt.MOD_HEADER_WORDS + wire_fmt.CRC_WORDS
-        ws = sum(sw.shape[-1] for sw in sws) + sign_frame
-        wm = sum(qw.shape[-1] for qw in qws) + mod_frame
-        ber_s = bitchannel.ber_for_success(q, ws)
-        ber_v = bitchannel.ber_for_success(p, wm)
-        ks, kv = jax.random.split(ko)
-        qws, mod_ok, mod_flips = _bitlevel_tree_pass(
-            kv, qws, ber_v, mod_frame, K, **shard_kw)
-        orig_sws = sws      # pristine payloads: retransmissions resend these
-        sws, sign_ok, sign_flips = _bitlevel_tree_pass(
-            ks, sws, ber_s, sign_frame, K, **shard_kw)
-        sign_crc_ok = sign_ok
-        retx_k = jnp.zeros((K,), jnp.int32)
-        for attempt in range(1, n_retx + 1):
-            failed = ~sign_ok
-            rx_a, ok_a, flips_a = _bitlevel_tree_pass(
-                jax.random.fold_in(ks, attempt), orig_sws, ber_s,
-                sign_frame, K, **shard_kw)
-            rescued = failed & ok_a
-            sws = [jnp.where(rescued[:, None], a, r)
-                   for a, r in zip(rx_a, sws)]
-            sign_flips = sign_flips + jnp.where(failed, flips_a, 0)
-            retx_k = retx_k + failed.astype(jnp.int32)
-            sign_ok = sign_ok | rescued
-        retx = jnp.sum(retx_k).astype(jnp.float32)
-        extras = dict(sign_flips=sign_flips, mod_flips=mod_flips,
-                      sign_crc_ok=sign_crc_ok, mod_crc_ok=mod_ok,
-                      retx_attempts=retx_k)
+        with stage_scope('corrupt_fold'):
+            sign_frame = wire_fmt.SIGN_HEADER_WORDS + wire_fmt.CRC_WORDS
+            mod_frame = wire_fmt.MOD_HEADER_WORDS + wire_fmt.CRC_WORDS
+            ws = sum(sw.shape[-1] for sw in sws) + sign_frame
+            wm = sum(qw.shape[-1] for qw in qws) + mod_frame
+            ber_s = bitchannel.ber_for_success(q, ws)
+            ber_v = bitchannel.ber_for_success(p, wm)
+            ks, kv = jax.random.split(ko)
+            qws, mod_ok, mod_flips = _bitlevel_tree_pass(
+                kv, qws, ber_v, mod_frame, K, **shard_kw)
+            # pristine payloads: retransmissions resend these
+            orig_sws = sws
+            sws, sign_ok, sign_flips = _bitlevel_tree_pass(
+                ks, sws, ber_s, sign_frame, K, **shard_kw)
+            sign_crc_ok = sign_ok
+            retx_k = jnp.zeros((K,), jnp.int32)
+            for attempt in range(1, n_retx + 1):
+                failed = ~sign_ok
+                rx_a, ok_a, flips_a = _bitlevel_tree_pass(
+                    jax.random.fold_in(ks, attempt), orig_sws, ber_s,
+                    sign_frame, K, **shard_kw)
+                rescued = failed & ok_a
+                sws = [jnp.where(rescued[:, None], a, r)
+                       for a, r in zip(rx_a, sws)]
+                sign_flips = sign_flips + jnp.where(failed, flips_a, 0)
+                retx_k = retx_k + failed.astype(jnp.int32)
+                sign_ok = sign_ok | rescued
+            retx = jnp.sum(retx_k).astype(jnp.float32)
+            extras = dict(sign_flips=sign_flips, mod_flips=mod_flips,
+                          sign_crc_ok=sign_crc_ok, mod_crc_ok=mod_ok,
+                          retx_attempts=retx_k)
     elif n_retx == 0:
         sign_ok, mod_ok = chan.simulate_outcomes(ko, q_eff, p)
         retx = jnp.zeros((), jnp.float32)
@@ -825,58 +828,60 @@ def spfl_aggregate_tree(grads_tree, gbar_tree, q: Array, p: Array,
     if screen:
         # tree path: norm-report screening only (votes are discarded at
         # LLM scale — see the docstring)
-        gate, suspect, suspicion = adv_screen.screen_gate(
-            g_max_rep, mod_ok, z_thresh=screen_z)
-        w = w * gate
+        with stage_scope('screen'):
+            gate, suspect, suspicion = adv_screen.screen_gate(
+                g_max_rep, mod_ok, z_thresh=screen_z)
+            w = w * gate
         extras['suspect'] = suspect
         extras['suspicion'] = suspicion
     denom = _present_denom(K, active, suspect)
 
     # ---- PS: decode-once aggregate per leaf ----
-    out = []
-    for i, (lf, gbar_leaf) in enumerate(zip(leaves, gbar_leaves)):
-        qg = qgs[i]
-        shape = lf.shape
-        Kd = shape[0]
-        gb = gbar_leaf.astype(jnp.float32)
-        per_client_gb = gb.shape == shape           # last_local vs shared
-        if wire == 'packed':
-            # the cross-client collective consumes the packed (K, W)
-            # payload words directly: one fused unpack->dequant->weight->
-            # accumulate kernel launch per leaf, no K unpack passes and
-            # no (K, d) float intermediate (the bf16 reduce is subsumed —
-            # the packed words are 4x narrower than bf16 at b=3); under
-            # 'sharded' each device accumulates its local clients and
-            # ONE d-float psum finishes the leaf (no vote psum: the tree
-            # path discards votes, so the partial traffic stays d floats)
-            d = qg.sign.shape[-1]
-            gb_leaf = (gb.reshape(Kd, -1) if per_client_gb
-                       else gb.reshape(-1))
-            if sharded:
-                acc, _ = kops.spfl_aggregate_packed_sharded(
-                    sws[i], qws[i], gb_leaf, g_min_rep, g_max_rep,
-                    mod_ok, w, sign_ok, d, bits, mesh=mesh,
-                    client_axes=client_axes, with_votes=False)
+    with stage_scope('decode_aggregate'):
+        out = []
+        for i, (lf, gbar_leaf) in enumerate(zip(leaves, gbar_leaves)):
+            qg = qgs[i]
+            shape = lf.shape
+            Kd = shape[0]
+            gb = gbar_leaf.astype(jnp.float32)
+            per_client_gb = gb.shape == shape           # last_local vs shared
+            if wire == 'packed':
+                # the cross-client collective consumes the packed (K, W)
+                # payload words directly: one fused unpack->dequant->weight->
+                # accumulate kernel launch per leaf, no K unpack passes and
+                # no (K, d) float intermediate (the bf16 reduce is subsumed —
+                # the packed words are 4x narrower than bf16 at b=3); under
+                # 'sharded' each device accumulates its local clients and
+                # ONE d-float psum finishes the leaf (no vote psum: the tree
+                # path discards votes, so the partial traffic stays d floats)
+                d = qg.sign.shape[-1]
+                gb_leaf = (gb.reshape(Kd, -1) if per_client_gb
+                           else gb.reshape(-1))
+                if sharded:
+                    acc, _ = kops.spfl_aggregate_packed_sharded(
+                        sws[i], qws[i], gb_leaf, g_min_rep, g_max_rep,
+                        mod_ok, w, sign_ok, d, bits, mesh=mesh,
+                        client_axes=client_axes, with_votes=False)
+                else:
+                    acc, _ = kops.spfl_aggregate_packed(
+                        sws[i], qws[i], gb_leaf,
+                        g_min_rep, g_max_rep, mod_ok, w, sign_ok, d, bits)
+                out.append((acc / denom).reshape(shape[1:]))
+                continue
+            modulus = dequantize_modulus(qg)
+            if per_client_gb:
+                gb = gb.reshape(Kd, -1)
             else:
-                acc, _ = kops.spfl_aggregate_packed(
-                    sws[i], qws[i], gb_leaf,
-                    g_min_rep, g_max_rep, mod_ok, w, sign_ok, d, bits)
-            out.append((acc / denom).reshape(shape[1:]))
-            continue
-        modulus = dequantize_modulus(qg)
-        if per_client_gb:
-            gb = gb.reshape(Kd, -1)
-        else:
-            gb = jnp.broadcast_to(gb.reshape(1, -1), modulus.shape)
-        modulus = jnp.where(mod_ok[:, None], modulus, gb)
-        signed = qg.sign.astype(jnp.float32) * modulus
-        contrib = (w[:, None] * signed).astype(rdt)
-        # keep the reduction itself (-> cross-client all-reduce) in rdt,
-        # and as a parallel jnp.sum: the client axis is mesh-sharded at
-        # LLM scale and must lower to ONE all-reduce
-        out.append((jnp.sum(contrib, axis=0) / denom).astype(
-            jnp.float32).reshape(shape[1:]))
-    ghat = jax.tree.unflatten(treedef, out)
+                gb = jnp.broadcast_to(gb.reshape(1, -1), modulus.shape)
+            modulus = jnp.where(mod_ok[:, None], modulus, gb)
+            signed = qg.sign.astype(jnp.float32) * modulus
+            contrib = (w[:, None] * signed).astype(rdt)
+            # keep the reduction itself (-> cross-client all-reduce) in rdt,
+            # and as a parallel jnp.sum: the client axis is mesh-sharded at
+            # LLM scale and must lower to ONE all-reduce
+            out.append((jnp.sum(contrib, axis=0) / denom).astype(
+                jnp.float32).reshape(shape[1:]))
+        ghat = jax.tree.unflatten(treedef, out)
 
     l = stats['dim']
     if wire == 'packed':

@@ -12,7 +12,8 @@ Channels mirror the quantities the paper reasons about analytically:
 * ``bitchannel`` — empirical (CRC-detected) vs calibrated erasure rates,
   the eq. (11)/(13) calibration residual surfaced as a gauge pair.
 * ``allocation`` — q/p mean gauges + histograms, the eq. (28) objective
-  trajectory, host_solver_calls (the counter the jax backend keeps at 0).
+  trajectory, solver effort (``alloc_iters``/``alloc_steps``),
+  host_solver_calls (the counter the jax backend keeps at 0).
 
 Histograms use seeded reservoir sampling (Vitter's algorithm R) so a
 fixed-seed run snapshots deterministically regardless of round count.
@@ -187,13 +188,15 @@ class MetricsRegistry:
         if obj is not None:
             al.histogram('objective_hist').observe(obj)
             al.gauge('objective').set(obj)
-        # solver effort: iterations-to-converge histogram + exit-reason
-        # counters make the accuracy-vs-wall-time map reconstructible
-        # from the metrics snapshot alone (NaN = path didn't solve)
-        iters = row.get('alloc_iters')
-        if iters is not None and not math.isnan(iters):
-            al.gauge('alloc_iters').set(iters)
-            al.histogram('alloc_iters_hist').observe(iters)
+        # solver effort: outer-iteration and inner-step histograms +
+        # exit-reason counters make the accuracy-vs-wall-time map
+        # reconstructible from the metrics snapshot alone (NaN = path
+        # didn't solve)
+        for name in ('alloc_iters', 'alloc_steps'):
+            effort = row.get(name)
+            if effort is not None and not math.isnan(effort):
+                al.gauge(name).set(effort)
+                al.histogram(f'{name}_hist').observe(effort)
         reason = row.get('alloc_exit_reason')
         if reason is not None and not math.isnan(reason):
             al.counter(f'alloc_exit_reason_{int(reason)}').inc(1.0)

@@ -44,7 +44,8 @@ Array = jax.Array
 # (training.fl_loop appends one entry per key per round at flush)
 SCALAR_KEYS = ('payload_bits', 'retransmissions', 'sign_ok_frac',
                'mod_ok_frac', 'q_mean', 'p_mean', 'sign_agreement',
-               'alloc_iters', 'alloc_exit_reason', 'participation_frac',
+               'alloc_iters', 'alloc_steps', 'alloc_exit_reason',
+               'participation_frac',
                'suspect_frac')
 # per-client (K,) vectors serialized into JSONL rows when present
 VECTOR_KEYS = ('sign_ok', 'mod_ok', 'accepted', 'sign_flips', 'mod_flips',
@@ -81,6 +82,10 @@ class RoundTelemetry(NamedTuple):
     #   agreement (see :meth:`condensed`); supersedes ``sign_votes`` when set
     alloc_iters: Optional[Array] = None   # scalar int32 — solver outer
     #   iterations to converge this round (early-exit effort telemetry)
+    alloc_steps: Optional[Array] = None   # scalar int32 — trips of the
+    #   solver's innermost serial loops this round (golden-section steps
+    #   of the alternating solve, descent steps of the barrier; 0 on the
+    #   uniform branch)
     alloc_exit_reason: Optional[Array] = None  # scalar int32 — the
     #   solver's EXIT_* code (core.allocation_jax: 0 converged,
     #   1 iteration cap, 2 non-finite iterate, 3 uniform fallback)
@@ -99,7 +104,8 @@ class RoundTelemetry(NamedTuple):
                         objective: Optional[Array] = None,
                         round_idx: Optional[Array] = None,
                         iters: Optional[Array] = None,
-                        exit_reason: Optional[Array] = None
+                        exit_reason: Optional[Array] = None,
+                        steps: Optional[Array] = None
                         ) -> 'RoundTelemetry':
         """Attach the round's allocation state (device arrays, no host
         transfer — pure ``_replace``)."""
@@ -112,6 +118,8 @@ class RoundTelemetry(NamedTuple):
             kw['alloc_iters'] = iters
         if exit_reason is not None:
             kw['alloc_exit_reason'] = exit_reason
+        if steps is not None:
+            kw['alloc_steps'] = steps
         return self._replace(**kw)
 
     def condensed(self) -> 'RoundTelemetry':
@@ -163,6 +171,8 @@ def round_scalars(t: RoundTelemetry) -> Dict[str, Array]:
                            else sign_agreement(t.sign_votes, t.sign_ok)),
         'alloc_iters': nan if t.alloc_iters is None else jnp.asarray(
             t.alloc_iters, jnp.float32),
+        'alloc_steps': nan if t.alloc_steps is None else jnp.asarray(
+            t.alloc_steps, jnp.float32),
         'alloc_exit_reason': nan if t.alloc_exit_reason is None
         else jnp.asarray(t.alloc_exit_reason, jnp.float32),
         'participation_frac': nan if t.active is None else jnp.mean(
@@ -212,6 +222,8 @@ def to_row(t: RoundTelemetry, round_idx: Optional[int] = None
         'sign_agreement': agreement,
         'alloc_iters': math.nan if t.alloc_iters is None
         else _np_scalar(t.alloc_iters),
+        'alloc_steps': math.nan if t.alloc_steps is None
+        else _np_scalar(t.alloc_steps),
         'alloc_exit_reason': math.nan if t.alloc_exit_reason is None
         else _np_scalar(t.alloc_exit_reason),
         'alloc_objective': None if t.alloc_objective is None

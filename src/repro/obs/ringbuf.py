@@ -26,6 +26,8 @@ from typing import Any, List, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import span
+
 
 class TelemetryRing(NamedTuple):
     """Device-resident ring: ``buf`` holds each record leaf stacked to
@@ -88,14 +90,23 @@ push = jax.jit(ring_push, donate_argnums=0)
 def flush(ring: TelemetryRing) -> Tuple[List[Any], TelemetryRing]:
     """Drain the ring: ONE device->host transfer of the stacked buffer,
     sliced into per-round host records (oldest first), plus a reset ring
-    that reuses the device buffer.  The only obs call that syncs."""
-    buf, idx = jax.device_get((ring.buf, ring.idx))
-    n = int(idx)
-    cap = ring.capacity
-    if n <= cap:
-        order = range(n)
-    else:                         # wrapped: oldest surviving slot first
-        start = n % cap
-        order = list(range(start, cap)) + list(range(start))
-    rows = [jax.tree.map(lambda b, i=i: b[i], buf) for i in order]
-    return rows, TelemetryRing(ring.buf, jnp.zeros((), jnp.int32))
+    that reuses the device buffer.  The only obs call that syncs.
+
+    Recorded as the host span ``flush`` with the children ``flush.get``
+    (the transfer, which waits for the device), ``flush.unstack`` and
+    ``flush.reset``."""
+    with span('flush'):
+        with span('flush.get'):
+            buf, idx = jax.device_get((ring.buf, ring.idx))
+        with span('flush.unstack'):
+            n = int(idx)
+            cap = ring.capacity
+            if n <= cap:
+                order = range(n)
+            else:                 # wrapped: oldest surviving slot first
+                start = n % cap
+                order = list(range(start, cap)) + list(range(start))
+            rows = [jax.tree.map(lambda b, i=i: b[i], buf) for i in order]
+        with span('flush.reset'):
+            reset = TelemetryRing(ring.buf, jnp.zeros((), jnp.int32))
+    return rows, reset

@@ -12,6 +12,7 @@ File format — one JSON object per line, discriminated by ``type``:
     {"type": "manifest", ...}          # first line, always
     {"type": "round", "round": 0, ...} # one per flushed RoundTelemetry
     {"type": "spans", ...}             # StageTrace summary (optional)
+    {"type": "compile", ...}           # obs.trace compile counters (optional)
     {"type": "metrics", ...}           # MetricsRegistry snapshot (optional)
 
 Read back with :func:`read_jsonl`.
@@ -134,6 +135,9 @@ class JsonlSink:
     def write_spans(self, summary: Dict[str, Any]) -> None:
         self._emit({'type': 'spans', 'spans': summary})
 
+    def write_compile(self, counters: Dict[str, Any]) -> None:
+        self._emit({'type': 'compile', 'compile': counters})
+
     def write_metrics(self, snapshot: Dict[str, Any]) -> None:
         self._emit({'type': 'metrics', 'metrics': snapshot})
 
@@ -151,7 +155,8 @@ class JsonlSink:
 def read_jsonl(path: str) -> Tuple[Optional[Dict[str, Any]],
                                    List[Dict[str, Any]]]:
     """-> (manifest or None, [round rows, oldest first]).  Other line
-    types (spans/metrics) are skipped; use json directly for those."""
+    types (spans/compile/metrics) are skipped; use json directly for
+    those."""
     manifest = None
     rows: List[Dict[str, Any]] = []
     with open(path) as f:

@@ -45,6 +45,7 @@ from repro.core import transport as tr
 from repro.models import transformer as tf
 from repro.obs import ringbuf as obs_ring
 from repro.obs.record import round_scalars
+from repro.obs.trace import stage_scope
 from repro.training.optimizer import Optimizer, sgd
 
 
@@ -229,11 +230,11 @@ def make_fused_fl_round(cfg: ModelConfig, fl: FLConfig,
                                           max_iters=max_iters,
                                           tol=alloc_tol,
                                           early_exit=early_exit)
-            return s.q, s.p, s.objective, s.iters, s.exit_reason
+            return s.q, s.p, s.objective, s.iters, s.exit_reason, s.steps
 
         def uniform(_):
             s = alloc_jax.solve_traceable(prob, 'uniform')
-            return s.q, s.p, s.objective, s.iters, s.exit_reason
+            return s.q, s.p, s.objective, s.iters, s.exit_reason, s.steps
 
         if method == 'uniform':
             return uniform(None)
@@ -250,26 +251,31 @@ def make_fused_fl_round(cfg: ModelConfig, fl: FLConfig,
         def one(bk):
             return jax.value_and_grad(client_loss)(params, bk)
 
-        losses, grads = jax.vmap(one)(batch)
+        # every stage runs under its obs/ scope (obs.trace.STAGES)
+        with stage_scope('grad'):
+            losses, grads = jax.vmap(one)(batch)
+            loss_mean = jnp.mean(losses)
+            stats = tr.tree_client_stats(grads)
 
         # population mode hands the sampled cohort in: per-device power
         # class, per-id byzantine membership and arrival raggedness all
         # derive from the cohort's global ids (lazily, O(cohort))
-        if cohort is not None:
-            p_w_n = cohort.p_w
-            byz_n = (pop.byzantine_ids(pop_key, cohort.ids,
-                                       fl.attack_frac)
-                     if fl.attack != 'none' else None)
-            present = cohort.present if ragged else None
-        else:
-            p_w_n, byz_n, present = p_w, byz_mask, None
-        active = pop.combine_active(present, draw_active(key))
+        with stage_scope('fading'):
+            if cohort is not None:
+                p_w_n = cohort.p_w
+                byz_n = (pop.byzantine_ids(pop_key, cohort.ids,
+                                           fl.attack_frac)
+                         if fl.attack != 'none' else None)
+                present = cohort.present if ragged else None
+            else:
+                p_w_n, byz_n, present = p_w, byz_mask, None
+            active = pop.combine_active(present, draw_active(key))
 
-        stats = tr.tree_client_stats(grads)
-        obj = iters = reason = None
+        obj = iters = reason = steps = None
         if transport_kind == 'spfl':
-            q, p, obj, iters, reason = alloc_f32(grads, gbar, stats,
-                                                 gains, p_w_n)
+            with stage_scope('alloc_solve'):
+                q, p, obj, iters, reason, steps = alloc_f32(
+                    grads, gbar, stats, gains, p_w_n)
             ghat, _, diag = tr.spfl_aggregate_tree(
                 grads, gbar, q, p, fl, key, stats=stats, wire=fl.wire,
                 channel=fl.channel, mesh=mesh, round_idx=round_idx,
@@ -285,14 +291,17 @@ def make_fused_fl_round(cfg: ModelConfig, fl: FLConfig,
                 grads, fl, key, stats=stats, wire=fl.wire, mesh=mesh,
                 round_idx=round_idx)
 
-        new_params, new_opt = opt.update(ghat, opt_state, params)
-        new_gbar = jax.tree.map(lambda g: jnp.abs(g), ghat)
-        rec = diag.with_allocation(q, p, objective=obj,
-                                   round_idx=round_idx, iters=iters,
-                                   exit_reason=reason).condensed()
-        if cohort is not None:
-            rec = rec._replace(cohort_ids=cohort.ids)
-        return new_params, new_opt, new_gbar, rec, jnp.mean(losses)
+        with stage_scope('update'):
+            new_params, new_opt = opt.update(ghat, opt_state, params)
+            new_gbar = jax.tree.map(lambda g: jnp.abs(g), ghat)
+        with stage_scope('telemetry'):
+            rec = diag.with_allocation(q, p, objective=obj,
+                                       round_idx=round_idx, iters=iters,
+                                       exit_reason=reason,
+                                       steps=steps).condensed()
+            if cohort is not None:
+                rec = rec._replace(cohort_ids=cohort.ids)
+        return new_params, new_opt, new_gbar, rec, loss_mean
 
     return round_fn
 
@@ -347,13 +356,16 @@ def make_fused_fl_scan(cfg: ModelConfig, fl: FLConfig, base_gains,
             # cohort gather inside the scan body: membership from the
             # round key (bit-identical to the eager dispatch), state
             # from the static population key — O(cohort), stateless
-            cohort = pop.sample_cohort(kr, pop_key, fl)
-            gains_n = pop.cohort_gains(pop_key, cohort.ids, n, fl,
-                                       shadowing=per_round_gains)
-            z2, batch = z, batch_fn(n, cohort.ids)
+            with stage_scope('fading'):
+                cohort = pop.sample_cohort(kr, pop_key, fl)
+                gains_n = pop.cohort_gains(pop_key, cohort.ids, n, fl,
+                                           shadowing=per_round_gains)
+                z2, batch = z, batch_fn(n, cohort.ids)
         elif per_round_gains:
-            z2 = channel.shadow_step(jax.random.fold_in(kr, 0x5AD0), z)
-            gains_n = channel.shadow_gains(gains_j, z2)
+            with stage_scope('fading'):
+                z2 = channel.shadow_step(jax.random.fold_in(kr, 0x5AD0),
+                                         z)
+                gains_n = channel.shadow_gains(gains_j, z2)
             cohort, batch = None, batch_fn(n)
         else:
             z2, gains_n = z, gains_j
@@ -369,7 +381,8 @@ def make_fused_fl_scan(cfg: ModelConfig, fl: FLConfig, base_gains,
             params, opt_state, gbar, key, z, kr, n)
         # the traceable push (the donated jitted wrapper cannot appear
         # inside a scan body)
-        ring2 = obs_ring.ring_push(ring, rec)
+        with stage_scope('telemetry'):
+            ring2 = obs_ring.ring_push(ring, rec)
         return (params2, opt2, gbar2, key, z2, ring2), loss
 
     def init_carry(params, key, seg_len: int):

@@ -66,9 +66,10 @@ from repro.core import quantize as quantize_mod
 from repro.models.cnn import cnn_accuracy, cnn_forward, cnn_loss, init_cnn
 from repro.obs import record as obs_record
 from repro.obs import ringbuf as obs_ring
+from repro.obs import trace as obs_trace
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.sink import JsonlSink, run_manifest
-from repro.obs.trace import StageTrace
+from repro.obs.trace import stage_scope
 
 
 @dataclass
@@ -85,6 +86,8 @@ class FLHistory:
     sign_agreement: List[float] = field(default_factory=list)  # packed wire
     alloc_iters: List[float] = field(default_factory=list)     # solver outer
     # iterations to converge (NaN on rounds/paths without a solve)
+    alloc_steps: List[float] = field(default_factory=list)     # solver inner
+    # loop trips (RoundTelemetry.alloc_steps; NaN likewise)
     alloc_exit_reason: List[float] = field(default_factory=list)  # EXIT_*
     retransmissions: List[float] = field(default_factory=list)
     # adversarial-cohort telemetry (populated when the knobs are on):
@@ -205,10 +208,10 @@ class FLSimulator:
         else:
             self.gbar = jnp.zeros((self.dim,))
         self._round = 0
-        # host-side stage spans (alloc_solve / update; the jitted interior
-        # stages are named_scope'd inside transport/kernels).  Opt into
-        # jax.profiler trace annotations with StageTrace(annotate=True).
-        self.trace = StageTrace()
+        # host spans (the process-wide record: fused_segment, the
+        # telemetry flush, alloc_solve / update of the host loop); the
+        # stages inside the jitted round are obs/ scopes (obs.trace.STAGES)
+        self.trace = obs_trace.SPANS
         # host metrics channels, fed from flushed telemetry rows
         self.metrics = MetricsRegistry()
         self._build_jits()
@@ -312,23 +315,26 @@ class FLSimulator:
                                                   tol=alloc_tol,
                                                   early_exit=early_exit)
                     return (s.alpha, s.beta, s.q, s.p, s.objective,
-                            s.iters, s.exit_reason)
+                            s.iters, s.exit_reason, s.steps)
 
                 def uniform(_):
                     s = alloc_jax.solve_traceable(prob, 'uniform')
                     return (s.alpha, s.beta, s.q, s.p, s.objective,
-                            s.iters, s.exit_reason)
+                            s.iters, s.exit_reason, s.steps)
 
                 if method == 'uniform':
-                    alpha, beta, q, p, obj, iters, reason = uniform(None)
+                    alpha, beta, q, p, obj, iters, reason, steps = uniform(
+                        None)
                 else:
                     # no compensation history yet (round 0): optimizing
                     # against gbar=0 degenerates to alpha=1 / ghat=0
-                    alpha, beta, q, p, obj, iters, reason = jax.lax.cond(
-                        jnp.max(gb2) > 0.0, solved, uniform, None)
+                    (alpha, beta, q, p, obj, iters, reason,
+                     steps) = jax.lax.cond(jnp.max(gb2) > 0.0, solved,
+                                           uniform, None)
                 return (q.astype(jnp.float32), p.astype(jnp.float32),
                         alpha.astype(jnp.float32),
-                        beta.astype(jnp.float32), obj, iters, reason)
+                        beta.astype(jnp.float32), obj, iters, reason,
+                        steps)
 
             # traced (and always re-entered) under x64: the closed forms
             # overflow f32 — see allocation_jax's precision contract
@@ -428,11 +434,13 @@ class FLSimulator:
                                               max_iters=max_iters,
                                               tol=alloc_tol,
                                               early_exit=early_exit)
-                return s.q, s.p, s.objective, s.iters, s.exit_reason
+                return (s.q, s.p, s.objective, s.iters, s.exit_reason,
+                        s.steps)
 
             def uniform(_):
                 s = alloc_jax.solve_traceable(prob, 'uniform')
-                return s.q, s.p, s.objective, s.iters, s.exit_reason
+                return (s.q, s.p, s.objective, s.iters, s.exit_reason,
+                        s.steps)
 
             if method == 'uniform':
                 return uniform(None)
@@ -442,85 +450,96 @@ class FLSimulator:
             return jax.lax.cond(jnp.max(gb2) > 0.0, solved, uniform, None)
 
         def round_core(params, gbar, kr, z, st, n):
+            # every stage runs under its obs/ scope (obs.trace.STAGES),
+            # which names its device ops in the compiled program
             if population:
                 # cohort gather: O(cohort) draws keyed off the per-round
                 # key kr (identical across none/eager/scan dispatch) and
                 # the static population key — per-device geometry, power
                 # class and shadowing are lazily materialized for the
                 # sampled ids only
-                cohort = pop.sample_cohort(kr, pop_key, fl)
-                shards = pop.shard_ids(cohort.ids, n_shards)
-                xs = jnp.take(self.client_x, shards, axis=0)
-                ys = jnp.take(self.client_y, shards, axis=0)
-                present = cohort.present if ragged else None
-                p_w_n = cohort.p_w
-                byz_n = (pop.byzantine_ids(pop_key, cohort.ids,
-                                           fl.attack_frac)
-                         if fl.attack != 'none' else None)
+                with stage_scope('fading'):
+                    cohort = pop.sample_cohort(kr, pop_key, fl)
+                    shards = pop.shard_ids(cohort.ids, n_shards)
+                    xs = jnp.take(self.client_x, shards, axis=0)
+                    ys = jnp.take(self.client_y, shards, axis=0)
+                    present = cohort.present if ragged else None
+                    p_w_n = cohort.p_w
+                    byz_n = (pop.byzantine_ids(pop_key, cohort.ids,
+                                               fl.attack_frac)
+                             if fl.attack != 'none' else None)
             else:
                 cohort = None
                 xs, ys = self.client_x, self.client_y
                 present, p_w_n, byz_n = None, p_w_j, None
 
-            losses, grads = self._per_client_grads(params, xs, ys)
+            with stage_scope('grad'):
+                losses, grads = self._per_client_grads(params, xs, ys)
+                loss_mean = jnp.mean(losses)
 
-            if population:
-                # shadowing is stateless in population mode — keyed by
-                # (device id, round n), so a device's track is the same
-                # whether or not it was sampled in between (population.
-                # shadow_at); the z carry passes through untouched
-                z2 = z
-                gains_n = pop.cohort_gains(pop_key, cohort.ids, n, fl,
-                                           shadowing=per_round_gains)
-            elif per_round_gains and allocating:
-                z2 = channel.shadow_step(jax.random.fold_in(kr, 0x5AD0), z)
-                gains_n = channel.shadow_gains(gains_j, z2)
-            else:
-                z2 = z
-                gains_n = gains_j
+            with stage_scope('fading'):
+                if population:
+                    # shadowing is stateless in population mode — keyed
+                    # by (device id, round n), so a device's track is the
+                    # same whether or not it was sampled in between
+                    # (population.shadow_at); the z carry passes through
+                    z2 = z
+                    gains_n = pop.cohort_gains(pop_key, cohort.ids, n, fl,
+                                               shadowing=per_round_gains)
+                elif per_round_gains and allocating:
+                    z2 = channel.shadow_step(
+                        jax.random.fold_in(kr, 0x5AD0), z)
+                    gains_n = channel.shadow_gains(gains_j, z2)
+                else:
+                    z2 = z
+                    gains_n = gains_j
 
-            # straggler chain: its own fold of the round key, so eager,
-            # scan and the host loop draw bit-identical dropouts and the
-            # existing streams (quantizer, channel) are unperturbed
-            if dropout:
-                st2, s_active = adversary.straggler_step(
-                    jax.random.fold_in(kr, adversary.STRAGGLER_FOLD),
-                    st, fl.dropout_rate, fl.straggler_stickiness)
-            else:
-                st2, s_active = st, None
-            # arrivals (ragged cohorts) compose with in-round stalls
-            active = pop.combine_active(present, s_active)
+                # straggler chain: its own fold of the round key, so
+                # eager, scan and the host loop draw bit-identical
+                # dropouts and the existing streams (quantizer, channel)
+                # are unperturbed
+                if dropout:
+                    st2, s_active = adversary.straggler_step(
+                        jax.random.fold_in(kr, adversary.STRAGGLER_FOLD),
+                        st, fl.dropout_rate, fl.straggler_stickiness)
+                else:
+                    st2, s_active = st, None
+                # arrivals (ragged cohorts) compose with in-round stalls
+                active = pop.combine_active(present, s_active)
 
-            obj = iters = reason = None
+            obj = iters = reason = steps = None
             if allocating:
-                q, p, obj, iters, reason = alloc_f32(grads, gbar,
-                                                     gains_n, p_w_n)
+                with stage_scope('alloc_solve'):
+                    q, p, obj, iters, reason, steps = alloc_f32(
+                        grads, gbar, gains_n, p_w_n)
             else:
                 q = jnp.ones(self.K)
                 p = jnp.ones(self.K)
 
             ghat, diag = self._run_transport(kind, grads, gbar, q, p,
                                              kr, n, active, byz_n)
-            new_params = self._apply_update(params, ghat)
 
-            if fl.compensation == 'last_global':
-                gbar2 = jnp.abs(ghat)
-            elif fl.compensation == 'last_local':
-                gbar2 = jnp.abs(grads)
-            elif fl.compensation == 'seeded_random':
-                gbar2 = jnp.abs(jax.random.normal(
-                    jax.random.fold_in(jax.random.PRNGKey(fl.seed + 99),
-                                       n),
-                    (dim,))) * 0.01
-            else:                    # zeros: leave as-is
-                gbar2 = gbar
+            with stage_scope('update'):
+                new_params = self._apply_update(params, ghat)
+                if fl.compensation == 'last_global':
+                    gbar2 = jnp.abs(ghat)
+                elif fl.compensation == 'last_local':
+                    gbar2 = jnp.abs(grads)
+                elif fl.compensation == 'seeded_random':
+                    gbar2 = jnp.abs(jax.random.normal(
+                        jax.random.fold_in(
+                            jax.random.PRNGKey(fl.seed + 99), n),
+                        (dim,))) * 0.01
+                else:                # zeros: leave as-is
+                    gbar2 = gbar
 
-            rec = diag.with_allocation(q, p, objective=obj, round_idx=n,
-                                       iters=iters,
-                                       exit_reason=reason).condensed()
-            if population:
-                rec = rec._replace(cohort_ids=cohort.ids)
-            return new_params, gbar2, z2, st2, rec, jnp.mean(losses)
+            with stage_scope('telemetry'):
+                rec = diag.with_allocation(
+                    q, p, objective=obj, round_idx=n, iters=iters,
+                    exit_reason=reason, steps=steps).condensed()
+                if population:
+                    rec = rec._replace(cohort_ids=cohort.ids)
+            return new_params, gbar2, z2, st2, rec, loss_mean
 
         return round_core
 
@@ -537,7 +556,8 @@ class FLSimulator:
                 params, gbar, kr, z, st, n)
             # the traceable push, NOT the donated jitted wrapper — the
             # ring is scan carry, donation is the dispatcher's business
-            ring2 = obs_ring.ring_push(ring, rec)
+            with stage_scope('telemetry'):
+                ring2 = obs_ring.ring_push(ring, rec)
             return (params2, gbar2, key, z2, st2, ring2), loss_mean
 
         return round_body
@@ -586,6 +606,7 @@ class FLSimulator:
                              "allocation_backend='jax' on allocating "
                              "transports (eq. (28) must solve in-trace)")
         hist = FLHistory()
+        span_mark = self.trace.mark()   # the sink's spans: this run's
         flush_every = max(1, fl.telemetry_flush_every)
         seg_len = fl.scan_segment_rounds or flush_every
         sink = (JsonlSink(fl.telemetry_path,
@@ -635,6 +656,7 @@ class FLSimulator:
                 if packed_agreement:
                     hist.sign_agreement.append(row['sign_agreement'])
                 hist.alloc_iters.append(row['alloc_iters'])
+                hist.alloc_steps.append(row['alloc_steps'])
                 hist.alloc_exit_reason.append(row['alloc_exit_reason'])
                 hist.retransmissions.append(row['retransmissions'])
                 if fl.dropout_rate > 0.0 or (
@@ -666,7 +688,8 @@ class FLSimulator:
         self._round += n_rounds
         self.metrics.observe_alloc(host_solver_calls=self.host_solver_calls)
         if sink is not None:
-            sink.write_spans(self.trace.summary())
+            sink.write_spans(self.trace.summary(since=span_mark))
+            sink.write_compile(obs_trace.compile_counters())
             sink.write_metrics(self.metrics.snapshot())
             sink.close()
         return hist
@@ -677,6 +700,7 @@ class FLSimulator:
         if self.fl.round_fusion != 'none':
             return self._run_fused(n_rounds, eval_every, compute_bound)
         hist = FLHistory()
+        span_mark = self.trace.mark()   # the sink's spans: this run's
         fl = self.fl
         kind = fl.transport
         if compute_bound and fl.allocation_backend == 'jax':
@@ -729,6 +753,7 @@ class FLSimulator:
                     # aligned with the other per-round histories
                     hist.sign_agreement.append(row['sign_agreement'])
                 hist.alloc_iters.append(row['alloc_iters'])
+                hist.alloc_steps.append(row['alloc_steps'])
                 hist.alloc_exit_reason.append(row['alloc_exit_reason'])
                 hist.retransmissions.append(row['retransmissions'])
                 if fl.dropout_rate > 0.0 or (
@@ -773,7 +798,7 @@ class FLSimulator:
             losses, grads = self._per_client_grads(self.params, xs, ys)
 
             ta = time.time()
-            alloc_obj = alloc_iters = alloc_reason = None
+            alloc_obj = alloc_iters = alloc_reason = alloc_steps = None
             with self.trace.span('alloc_solve'):
                 if kind in ('spfl', 'spfl_retx'):
                     if pop_mode:
@@ -790,7 +815,7 @@ class FLSimulator:
                         # x64 re-entry keeps the jit cache key stable)
                         with jax.enable_x64():
                             (q, p, _, _, alloc_obj, alloc_iters,
-                             alloc_reason) = self._alloc_jax(
+                             alloc_reason, alloc_steps) = self._alloc_jax(
                                 grads, self.gbar, gains_n, p_w_n)
                         sol, stats = None, None
                     else:
@@ -849,7 +874,8 @@ class FLSimulator:
             rec = diag.with_allocation(
                 q, p, objective=alloc_obj,
                 round_idx=jnp.uint32(self._round - 1),
-                iters=alloc_iters, exit_reason=alloc_reason).condensed()
+                iters=alloc_iters, exit_reason=alloc_reason,
+                steps=alloc_steps).condensed()
             if pop_mode:
                 rec = rec._replace(cohort_ids=cohort.ids)
             if ring is None:
@@ -870,7 +896,8 @@ class FLSimulator:
             hist.round_time_s.append(time.time() - t0)
         self.metrics.observe_alloc(host_solver_calls=self.host_solver_calls)
         if sink is not None:
-            sink.write_spans(self.trace.summary())
+            sink.write_spans(self.trace.summary(since=span_mark))
+            sink.write_compile(obs_trace.compile_counters())
             sink.write_metrics(self.metrics.snapshot())
             sink.close()
         return hist

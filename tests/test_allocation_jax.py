@@ -401,11 +401,97 @@ def test_inner_tol_frontier_within_contract(method):
     width / dual bisection / barrier displacement); the endpoint drift
     is bounded by the documented parity contract for the method."""
     tol = TOL[method]
+    cut = []
     for k, power, seed in [(4, -8.0, 41), (8, -26.0, 42)]:
         prob = _problem(k=k, power_dbm=power, seed=seed)
         exact = AJ.solve(prob, method, max_iters=3, inner_tol=0.0)
         fast = AJ.solve(prob, method, max_iters=3, inner_tol=1e-6)
+        # the tolerance exits never add counted work, and cut it here
+        assert 0 < fast.info['steps'] <= exact.info['steps']
+        cut.append(fast.info['steps'] < exact.info['steps'])
         assert fast.objective == pytest.approx(
             exact.objective, rel=tol['obj_rtol'], abs=1e-12)
         np.testing.assert_allclose(fast.q, exact.q, atol=tol['qp_atol'])
         np.testing.assert_allclose(fast.p, exact.p, atol=tol['qp_atol'])
+    assert any(cut)
+
+
+# ---------------------------------------------------------------------------
+# solver step counter (JaxAllocation.steps -> RoundTelemetry.alloc_steps)
+# ---------------------------------------------------------------------------
+
+# the duals the bracket growth tries: 1, 10, ..., 1e29 (30 trips at most)
+_GROW_DUALS = [0.0] + [10.0 ** i for i in range(30)]
+
+
+@jax.jit
+def _one_sca_round(prob, alpha, beta, duals):
+    """One SCA round from ``beta``: its step count, and the bandwidth sum
+    of the free search (the one ``optimize_beta_sca`` runs) at each of
+    ``duals``."""
+    surrogate = AJ._surrogate(prob, AJ._caps(prob.A.dtype), alpha, beta)
+
+    def total(lam):
+        b, _ = AJ._golden_vec(lambda x: surrogate(x) + lam * x,
+                              beta.shape, beta.dtype)
+        return AJ._msum(prob, b)
+
+    steps = AJ.optimize_beta_sca(prob, alpha, beta, sca_rounds=1)[1]
+    return steps, jax.vmap(total)(duals)
+
+
+def _sca_searches_by_hand(totals):
+    """Golden-section searches one SCA round runs, counted on the host
+    from the searches' own answers (``totals`` at ``_GROW_DUALS``): the
+    free search, and when its bandwidths overshoot the simplex, one
+    search per dual bracket growth (x10 from 1 while the sum stays
+    infeasible, 30 at most), one per bisection step and the final one."""
+    if totals[0] <= 1.0:
+        return 1
+    grows = 0
+    for hi, t in zip(_GROW_DUALS[1:], totals[1:]):
+        grows += 1
+        if not (t > 1.0 and hi < 1e30):
+            break
+    return 1 + grows + AJ.BISECTION_ITERS + 1
+
+
+@pytest.mark.parametrize('k,latency,searches', [(1, None, 1),
+                                                (4, None, 63),
+                                                (4, 0.002, 92)])
+def test_alloc_steps_hand_count_one_sca_round(k, latency, searches):
+    """One SCA round's step count is GOLDEN_ITERS per search, with the
+    searches counted by hand: a problem whose free search fits the
+    simplex (K=1), one that binds after one bracket growth, and one whose
+    bracket grows to its 30-trip cap (a latency budget tight enough that
+    every dual below 1e29 leaves the sum infeasible)."""
+    prob = _problem(k=k, power_dbm=-14.0, seed=3)
+    if latency is not None:
+        prob = dataclasses.replace(
+            prob, fl=dataclasses.replace(prob.fl, latency_s=latency))
+    with jax.enable_x64():
+        jp = AJ.from_reference(prob)
+        beta = jax.numpy.full((k,), 1.0 / k)
+        alpha = jax.numpy.full((k,), 0.5)
+        steps, totals = _one_sca_round(
+            jp, alpha, beta, jax.numpy.asarray(_GROW_DUALS))
+        assert _sca_searches_by_hand(np.asarray(totals)) == searches
+        assert int(steps) == searches * AJ.GOLDEN_ITERS
+
+
+@pytest.mark.parametrize('method', ['alternating', 'barrier'])
+def test_alloc_steps_lowering_invariant_and_zero_on_uniform(method):
+    """The whole solve's count is the same under the early-exit and the
+    fixed-trip lowering (frozen trips are not counted) and a whole number
+    of units: GOLDEN_ITERS per search for ``alternating``, one descent
+    step and its BACKTRACK_ITERS halvings for ``barrier``.  ``uniform``
+    counts 0."""
+    unit = (AJ.GOLDEN_ITERS if method == 'alternating'
+            else 1 + AJ.BACKTRACK_ITERS)
+    for power, seed in [(-8.0, 41), (-24.0, 6)]:
+        prob = _problem(k=4, power_dbm=power, seed=seed)
+        ee = AJ.solve(prob, method, max_iters=3, early_exit=True)
+        ft = AJ.solve(prob, method, max_iters=3, early_exit=False)
+        assert ee.info['steps'] == ft.info['steps'] > 0
+        assert ee.info['steps'] % unit == 0
+    assert AJ.solve(prob, 'uniform').info['steps'] == 0

@@ -13,8 +13,10 @@ import dataclasses
 import json
 import math
 import os
+import re
 import subprocess
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +32,9 @@ from repro.obs import (
     round_scalars, run_manifest, to_row,
 )
 from repro.obs import ringbuf as obs_ring
+from repro.obs.trace import (
+    SPAN_CAPACITY, SPANS, StageTrace, compile_counters, span,
+)
 from repro.training.fl_loop import FLHistory
 
 K, L = 4, 256
@@ -449,3 +454,157 @@ def test_report_history_single_and_empty_entries(tmp_path):
     # none of these raise; each prints a clear line instead
     for p in (single, empty, broken, malformed):
         rh.report(str(p))
+
+
+# ---------------------------------------------------------------------------
+# solver step counter, stage scopes, host spans, compile counters
+# ---------------------------------------------------------------------------
+
+def test_alloc_steps_survives_ring_flush_and_serializers():
+    """``alloc_steps`` rides the record like ``alloc_iters``: ring push,
+    flush, ``to_row``/``round_scalars`` and the allocation channel."""
+    rec = _mk_rec().with_allocation(
+        jnp.full((K,), 0.9), jnp.full((K,), 0.6), iters=jnp.int32(2),
+        steps=jnp.int32(6048))
+    ring = ring_push(ring_init(rec, 2), rec)
+    rows, _ = obs_ring.flush(ring)
+    row = to_row(rows[0])
+    assert row['alloc_steps'] == 6048.0
+    assert float(round_scalars(rows[0])['alloc_steps']) == 6048.0
+    assert math.isnan(to_row(_mk_rec())['alloc_steps'])
+    reg = MetricsRegistry()
+    reg.observe_round(row)
+    al = reg.snapshot()['allocation']
+    assert al['alloc_steps']['value'] == 6048.0
+    assert al['alloc_steps_hist']['count'] == 1
+
+
+def _hlo_sources(hlo: str):
+    """[(op_name, innermost repro/ file)] of the HLO instructions whose
+    metadata names a stack frame (the compiled module's frame tables)."""
+    tables, cur = {}, None
+    for line in hlo.splitlines():
+        if line in ('FileNames', 'FileLocations', 'StackFrames'):
+            cur = tables.setdefault(line, {})
+            continue
+        m = re.match(r'^(\d+) (.*)$', line) if cur is not None else None
+        if not m:
+            cur = None
+            continue
+        body = m.group(2)
+        cur[int(m.group(1))] = (body.strip('"') if body.startswith('"')
+                                else {k: int(v) for k, v in
+                                      re.findall(r'(\w+)=(\d+)', body)})
+
+    def source(frame):
+        seen = set()
+        while frame in tables['StackFrames'] and frame not in seen:
+            seen.add(frame)
+            fr = tables['StackFrames'][frame]
+            loc = tables['FileLocations'][fr['file_location_id']]
+            path = tables['FileNames'][loc['file_name_id']]
+            if 'repro/' in path:
+                return path
+            frame = fr['parent_frame_id']
+        return ''
+
+    return [(m.group(1), source(int(m.group(2)))) for m in re.finditer(
+        r'op_name="([^"]*)"[^}]*stack_frame_id=(\d+)', hlo)]
+
+
+def test_stage_scopes_name_the_compiled_fused_segment():
+    """Every stage of ``obs.trace.STAGES`` names ops of a compiled
+    2-round fused segment (packed wire, bit channel, screening, AR(1)
+    fading, in-trace solve); ``psum`` exists only on the sharded
+    collective, compiled here on the host mesh.  Every op whose source is
+    the model lies under ``obs/grad``."""
+    from repro.obs.trace import STAGES
+    from repro.training.fl_loop import FLSimulator
+    fl = FLConfig(n_devices=K, allocator='barrier', seed=0,
+                  allocation_backend='jax', round_fusion='scan',
+                  wire='packed', channel='bitlevel', screen=True,
+                  allocation_cadence='per_round', telemetry_flush_every=2)
+    rng = np.random.RandomState(0)
+    xs = rng.rand(K, 8, 32, 32, 3).astype(np.float32)
+    ys = rng.randint(0, 10, (K, 8)).astype(np.int32)
+    sim = FLSimulator(fl, xs, ys, xs[0], ys[0])
+    body = sim._fused_round_body()
+    hlo = jax.jit(lambda c, ns: jax.lax.scan(body, c, ns)).lower(
+        sim._fused_init_carry(2), jnp.arange(2, dtype=jnp.uint32)
+    ).compile().as_text()
+    grads = jnp.ones((K, L)) * 0.01
+    q = jnp.full((K,), 0.9)
+    hlo_psum = jax.jit(lambda kk: TR.spfl_aggregate(
+        grads, jnp.ones((L,)), q, q, 3, 32, kk, wire='packed',
+        collective='sharded', mesh=make_host_mesh())[0]).lower(
+            jax.random.PRNGKey(0)).compile().as_text()
+
+    def scopes(text):
+        return {seg[len('obs/'):].split('/')[0]
+                for name in re.findall(r'op_name="([^"]*)"', text)
+                for seg in re.findall(r'obs/[\w]+', name)}
+
+    found = scopes(hlo)
+    assert set(STAGES) - {'psum'} <= found, set(STAGES) - found
+    assert 'psum' in scopes(hlo_psum)
+    assert found | scopes(hlo_psum) <= set(STAGES)
+    model_ops = [name for name, src in _hlo_sources(hlo)
+                 if 'repro/models/' in src and name.startswith('jit(')]
+    assert model_ops
+    assert all('/obs/grad/' in name for name in model_ops), [
+        n for n in model_ops if '/obs/grad/' not in n][:3]
+
+
+def test_host_spans_nest_and_are_bounded():
+    tracer = StageTrace()
+    before = time.time_ns()
+    with tracer.span('outer'):
+        with tracer.span('inner'):
+            pass
+    after = time.time_ns()
+    inner, outer = tracer.spans()
+    assert (inner.name, outer.name) == ('inner', 'outer')
+    assert inner.parent == outer.id and outer.parent is None
+    assert before <= outer.start_ns <= inner.start_ns <= inner.end_ns \
+        <= outer.end_ns <= after
+    mark = tracer.mark()
+    for i in range(SPAN_CAPACITY + 1):
+        with tracer.span('s'):
+            pass
+    kept = tracer.spans()
+    assert len(kept) == SPAN_CAPACITY
+    assert kept[0].id == mark + 1 and kept[-1].id == mark + SPAN_CAPACITY
+    assert set(tracer.summary()) == {'s'}
+    assert tracer.summary(since=kept[-1].id)['s']['count'] == 1
+
+
+def test_flush_records_its_spans():
+    ring = ring_push(ring_init(_mk_rec(), 2), _mk_rec())
+    obs_ring.flush(ring)
+    *children, parent = SPANS.spans()[-4:]
+    assert parent.name == 'flush'
+    assert [c.name for c in children] == ['flush.get', 'flush.unstack',
+                                          'flush.reset']
+    assert all(c.parent == parent.id for c in children)
+
+
+def test_compile_counter_counts_a_fresh_jit():
+    x = jnp.arange(3.0)
+    before = compile_counters()
+    jax.jit(lambda v: v * 3.0 + 1.0)(x).block_until_ready()
+    after = compile_counters()
+    for kind in ('trace', 'lower', 'backend_compile'):
+        assert after[kind]['count'] == before[kind]['count'] + 1, kind
+        assert after[kind]['seconds'] > before[kind]['seconds'], kind
+    assert after['total_s'] > before['total_s']
+
+
+def test_host_spans_leave_compiled_hlo_unchanged():
+    def f(v):
+        return jnp.sin(v) * 2.0
+
+    x = jnp.arange(4.0)
+    plain = jax.jit(f).lower(x).compile().as_text()
+    with span('outer'):
+        inside = jax.jit(f).lower(x).compile().as_text()
+    assert inside == plain
