@@ -16,6 +16,14 @@ flow over an :class:`JaxAllocationProblem` pytree, so that
   one dispatch solves allocations for an entire block-fading trajectory
   or an SNR x K scenario grid.
 
+The dual bisection's searches run batched over duals, not one after
+another: one search covers lambda = 0 and every bracket growth
+candidate, and each later search covers the 127 midpoints of a depth-7
+bisection subtree, walked level by level with selects until the
+midpoint reaches an end of the bracket (``optimize_beta_sca``).  Every
+dual's column gets the bits its own search would, so the result is
+the serial bisection's, in far fewer serial steps.
+
 Control flow is masked AND convergence-aware: every early ``break`` of
 the reference becomes a frozen carry under a ``done`` flag with the
 same trip-count bounds, so the two engines walk the same iterates —
@@ -30,10 +38,10 @@ via select until the whole batch converges — exactly the masked
 all-converged predicate), and stays compilable inside ``lax.scan``
 (the predicate always includes the hard trip cap).  ``inner_tol > 0``
 additionally enables tolerance-bounded exits of the golden-section /
-dual-bisection / barrier-descent inner loops (interval width resp.
-iterate displacement below ``inner_tol``) — faster but no longer
-bit-identical; the measured accuracy-vs-wall-time frontier lives in
-``src/repro/core/README.md``.
+dual-bisection / barrier-descent inner loops (interval width, relative
+dual bracket width resp. iterate displacement below ``inner_tol``) —
+faster but no longer bit-identical; the measured accuracy-vs-wall-time
+frontier lives in ``src/repro/core/README.md``.
 
 Ragged cohorts batch through zero-padding: ``stack_problems`` with
 heterogeneous K pads every per-client leaf to the widest cohort and
@@ -108,6 +116,8 @@ class JaxAllocation(NamedTuple):
     steps: jax.Array             # (...,)  int32 trips of the innermost
     #   serial loops (see ``solve_traceable``): the solve's work, where
     #   ``iters`` counts only its outer iterations
+    dual_rounds: jax.Array       # (...,)  int32 tree rounds of the dual
+    #   bisection (``optimize_beta_sca``) over the whole solve
 
 
 class _Caps(NamedTuple):
@@ -427,7 +437,9 @@ def _surrogate(prob, caps, alpha, beta0):
 
 
 GOLDEN_ITERS = 48       # golden-section steps of one full search
-BISECTION_ITERS = 60    # dual bisection steps on the sum constraint
+BISECTION_ITERS = 60    # dual bisection levels on the sum constraint
+DUAL_GROWTHS = 30       # x10 growths of the dual bracket from 1 (to 1e30)
+TREE_DEPTH = 7          # bisection levels one tree round resolves
 BACKTRACK_ITERS = 27    # feasibility halvings of one barrier descent step
 
 
@@ -466,76 +478,178 @@ def _golden_vec(f, shape, dtype, iters: int = GOLDEN_ITERS,
     return 0.5 * (carry[0] + carry[1]), steps
 
 
+def _lanes(x, n: int):
+    """A (K,) per-client array broadcast to (K, n): one client per row,
+    one dual per lane."""
+    return jnp.broadcast_to(x[:, None], x.shape + (n,))
+
+
+def _client_rows(prob: JaxAllocationProblem, n: int) -> JaxAllocationProblem:
+    """The problem with every per-client field broadcast to (K, n).
+    Broadcast before a search, not inside it: every operand of a
+    golden-section step then has the step's own shape, so the TPU fuses
+    each evaluation of the step whole instead of recomputing and
+    relaying out the (K,)-shaped invariants in small fusions of their
+    own on every step."""
+    return prob._replace(
+        A=_lanes(prob.A, n), B=_lanes(prob.B, n), C=_lanes(prob.C, n),
+        D=_lanes(prob.D, n), gains=_lanes(prob.gains, n),
+        p_w=_lanes(prob.p_w, n),
+        mask=None if prob.mask is None else _lanes(prob.mask, n))
+
+
+def _growth_duals(dtype) -> np.ndarray:
+    """lambda = 0, then the dual bracket's candidates hi_0 = 1,
+    hi_{i+1} = hi_i * 10 up to hi_DUAL_GROWTHS, each multiplied in
+    ``dtype`` exactly as the serial growth chain does."""
+    dtype = np.dtype(dtype)
+    hi = np.asarray(1.0, dtype)
+    duals = [np.asarray(0.0, dtype), hi]
+    for _ in range(DUAL_GROWTHS):
+        hi = hi * np.asarray(10.0, dtype)
+        duals.append(hi)
+    return np.stack(duals)
+
+
+def _bisection_tree(lo, hi, depth: int):
+    """Intervals of the depth-``depth`` bisection tree below ``(lo, hi)``
+    in heap order (node i's children are 2i+1, the interval below its
+    midpoint, and 2i+2, the one above), leaves included: two
+    (2^(depth+1) - 1,) arrays.  Every midpoint is the serial
+    bisection's own ``0.5*(lo + hi)`` of its node's interval."""
+    ls, hs = [lo[None]], [hi[None]]
+    for _ in range(depth):
+        l, h = ls[-1], hs[-1]
+        m = 0.5 * (l + h)
+        ls.append(jnp.stack([l, m], -1).reshape(-1))
+        hs.append(jnp.stack([m, h], -1).reshape(-1))
+    return jnp.concatenate(ls), jnp.concatenate(hs)
+
+
 def optimize_beta_sca(prob: JaxAllocationProblem, alpha, beta0,
                       sca_rounds: int = 8, tol: float = 1e-6,
                       caps: _Caps = None, early_exit: bool = True,
                       inner_tol: float = 0.0):
-    """-> ``(beta, steps)``: ``steps`` sums the golden-section steps of
-    every search the SCA rounds ran — the free search, and when the sum
-    constraint binds, one per dual bracket growth and bisection step
-    plus the final one."""
+    """SCA rounds on beta, each a golden-section search per client under
+    a dual bisection on the sum-bandwidth constraint.
+
+    -> ``(beta, steps, dual_rounds)``.  Each round's searches are
+    batched over duals on a trailing lane axis (``_client_rows``), so
+    the serial schedule of the NumPy reference (a free search, up to
+    ``DUAL_GROWTHS`` bracket growths x10 from 1, ``BISECTION_ITERS``
+    bisection steps and a final search, every one a full search) runs
+    as few searches, with the same answer to the bit:
+
+    * one search over lambda = 0 and every growth candidate: the free
+      beta is its first column, and when that overshoots the simplex,
+      the bracket's ``hi`` is the first candidate the serial growth
+      would stop at (feasible, or at the 1e30 cap);
+    * then tree rounds: the 2^TREE_DEPTH - 1 midpoints below
+      ``(lo, hi)``, built by the serial ``0.5*(lo + hi)``, go through
+      one search, and the walk descends ``TREE_DEPTH`` levels with
+      selects, taking ``lo`` on an infeasible midpoint and ``hi`` on a
+      feasible one, as the serial step does; the beta of the current
+      ``hi`` is carried, so no final search is needed.  The rounds stop
+      after ``BISECTION_ITERS`` levels in all, or as soon as the next
+      midpoint equals ``lo`` or ``hi``: ``lo`` stays infeasible and
+      ``hi`` feasible (or at the cap), so every later serial step is an
+      exact fixed point of ``hi``.  In float32 that ends the search
+      after about 35 levels at the paper cell's settings; float64
+      mostly runs all 60.
+
+    Elementwise ops and ordered client sums give every dual's column
+    the bits a search of its own would give, so the result is the
+    serial bisection's (tests/test_allocation_jax.py pins it in f32
+    and f64).  ``steps`` sums ``GOLDEN_ITERS`` per search the SCA
+    rounds ran (one per round, plus one per tree round);
+    ``dual_rounds`` counts the tree rounds."""
     caps = caps or _caps(prob.A.dtype)
     dtype = beta0.dtype
-    shape = beta0.shape
-    # only the tolerance exit makes a search's step count vary; without
-    # it the count is GOLDEN_ITERS per search, multiplied out once per
-    # SCA round, so the grow and bisection bodies carry it unchanged
+    k = beta0.shape[-1]
+    growth = _growth_duals(dtype)
+    duals0 = jnp.asarray(growth)
+    # the serial growth continues past candidate i only while hi_i < 1e30
+    below_cap = growth[1:-1] < np.asarray(1e30, dtype)
+    n_nodes = 2 ** TREE_DEPTH - 1
+    max_rounds = -(-BISECTION_ITERS // TREE_DEPTH)
+    # only the tolerance exits make a search's step count vary; without
+    # them the count is GOLDEN_ITERS per search, multiplied out once
     tally = early_exit and inner_tol > 0.0
 
+    def halts(lo, hi):
+        """The serial bisection stops moving ``hi`` here: the midpoint
+        equals an end, or (tolerance exit) the bracket is that narrow."""
+        mid = 0.5 * (lo + hi)
+        out = (mid == lo) | (mid == hi)
+        if tally:
+            out = out | ((hi - lo) <= inner_tol * hi)
+        return out
+
     def sca_body(_, carry):
-        beta, prev, done, steps = carry
-        surrogate = _surrogate(prob, caps, alpha, beta)
+        beta, prev, done, steps, dual_rounds = carry
 
-        def beta_of_lambda(lam):
-            return _golden_vec(lambda b: surrogate(b) + lam * b, shape,
-                               dtype, early_exit=early_exit,
-                               width_tol=inner_tol)
+        def search(duals):
+            n = duals.shape[0]
+            # the barrier keeps XLA from folding the broadcasts back
+            # into the search loop (see ``_client_rows``).  It also fixes
+            # where the fields are computed: inside a larger jit XLA no
+            # longer folds their expressions into the consumers, so the
+            # answer's low bits can differ from a build that lets it
+            rows, alpha_rows, beta_rows = lax.optimization_barrier(
+                (_client_rows(prob, n), _lanes(alpha, n), _lanes(beta, n)))
+            surrogate = _surrogate(rows, caps, alpha_rows, beta_rows)
+            return _golden_vec(lambda b: surrogate(b) + duals * b,
+                               (k, n), dtype,
+                               early_exit=early_exit, width_tol=inner_tol)
 
-        b0, steps0 = beta_of_lambda(jnp.asarray(0.0, dtype))
+        # (K, N) -> (N,): each dual's own ordered client sum
+        bs, steps0 = search(duals0)
+        sums = _msum(prob, bs.T)
 
         def dual(_):
-            # grow the dual upper bracket (×10 from 1.0; 30 steps reach
-            # the reference's 1e30 stop); once `need` clears, further
-            # trips are frozen no-ops — the while form exits there
-            def grow(_, carry):
-                hi, cont, n = carry
-                b, t = beta_of_lambda(hi)
-                need = cont & (_msum(prob, b) > 1.0) & (hi < 1e30)
-                return (jnp.where(need, hi * 10.0, hi), need,
-                        n + t if tally else n)
+            # the first growth candidate where the serial loop stops
+            clear = ~((sums[1:-1] > 1.0) & below_cap)
+            first = jnp.where(jnp.any(clear), jnp.argmax(clear),
+                              DUAL_GROWTHS)
+            lo = jnp.asarray(0.0, dtype)
+            hi = duals0[1 + first]
+            b_hi = lax.dynamic_index_in_dim(bs, 1 + first, 1, False)
 
-            (hi, _, n_grow), grows = _bounded_fori(
-                30, grow, (jnp.asarray(1.0, dtype), jnp.asarray(True),
-                           jnp.int32(0)),
-                lambda c: ~c[1], early_exit)
+            def tree_round(_, carry):
+                lo, hi, b_hi, levels, done, n = carry
+                ls, hs = _bisection_tree(lo, hi, TREE_DEPTH)
+                stop = halts(ls, hs)
+                mids = 0.5 * (ls[:n_nodes] + hs[:n_nodes])
+                bt, t = search(mids)
+                infeas = _msum(prob, bt.T) > 1.0
+                node, hi_node, entered = 0, -1, done
+                for _ in range(TREE_DEPTH):
+                    done = done | stop[node] | (levels >= BISECTION_ITERS)
+                    inf = infeas[node]
+                    hi_node = jnp.where(~done & ~inf, node, hi_node)
+                    node = jnp.where(done, node, 2 * node + 1 + inf)
+                    levels = levels + (~done).astype(jnp.int32)
+                done = done | stop[node] | (levels >= BISECTION_ITERS)
+                b_hi = jnp.where(
+                    hi_node >= 0,
+                    lax.dynamic_index_in_dim(bt, jnp.maximum(hi_node, 0),
+                                             1, False), b_hi)
+                n = n + jnp.where(entered, 0, t) if tally else n
+                return ls[node], hs[node], b_hi, levels, done, n
 
-            # ... then 60 bisection steps on the sum constraint
-            # (``inner_tol`` stops once the dual bracket is relatively
-            # that narrow — the fixed-trip schedule reaches 2^-60)
-            def bis(_, lhn):
-                lo, hi, n = lhn
-                mid = 0.5 * (lo + hi)
-                b, t = beta_of_lambda(mid)
-                infeas = _msum(prob, b) > 1.0
-                return (jnp.where(infeas, mid, lo),
-                        jnp.where(infeas, hi, mid), n + t if tally else n)
+            (_, _, b, _, _, n), rounds = _bounded_fori(
+                max_rounds, tree_round,
+                (lo, hi, b_hi, jnp.int32(0), halts(lo, hi), jnp.int32(0)),
+                lambda c: c[4], early_exit)
+            searched = n if tally else GOLDEN_ITERS * rounds
+            return (b * jnp.minimum(1.0, 1.0 / jnp.maximum(
+                _msum(prob, b), 1e-12)), jnp.asarray(searched, jnp.int32),
+                rounds)
 
-            def bis_stop(lhn):
-                return (lhn[1] - lhn[0]) <= inner_tol * lhn[1]
-
-            (_, hi, n_bis), bisections = _bounded_fori(
-                BISECTION_ITERS, bis,
-                (jnp.asarray(0.0, dtype), hi, jnp.int32(0)), bis_stop,
-                early_exit and inner_tol > 0.0, freezes=False)
-            b, t = beta_of_lambda(hi)
-            searched = (n_grow + n_bis + t if tally
-                        else GOLDEN_ITERS * (grows + bisections + 1))
-            return b * jnp.minimum(1.0, 1.0 / jnp.maximum(
-                _msum(prob, b), 1e-12)), jnp.asarray(searched, jnp.int32)
-
-        b, steps_dual = lax.cond(
-            _msum(prob, b0) > 1.0, dual,
-            lambda _: (b0, jnp.int32(0)), None)
+        b0 = bs[:, 0]
+        b, steps_dual, rounds = lax.cond(
+            sums[0] > 1.0, dual,
+            lambda _: (b0, jnp.int32(0), jnp.int32(0)), None)
         # MM guarantee: only accept descent on the true objective
         cur = _objective(prob, caps, alpha, b)
         accept = (cur <= prev) & ~done
@@ -543,14 +657,15 @@ def optimize_beta_sca(prob: JaxAllocationProblem, alpha, beta0,
         beta2 = jnp.where(accept, b, beta)
         prev2 = jnp.where(done, prev, jnp.minimum(prev, cur))
         steps2 = jnp.where(done, steps, steps + steps0 + steps_dual)
-        return beta2, prev2, done | conv, steps2
+        rounds2 = jnp.where(done, dual_rounds, dual_rounds + rounds)
+        return beta2, prev2, done | conv, steps2, rounds2
 
     prev0 = _objective(prob, caps, alpha, beta0)
-    (beta, _, _, steps), _ = _bounded_fori(
+    (beta, _, _, steps, dual_rounds), _ = _bounded_fori(
         sca_rounds, sca_body,
-        (beta0, prev0, jnp.asarray(False), jnp.int32(0)),
+        (beta0, prev0, jnp.asarray(False), jnp.int32(0), jnp.int32(0)),
         lambda c: c[2], early_exit)
-    return beta, steps
+    return beta, steps, dual_rounds
 
 
 # ---------------------------------------------------------------------------
@@ -641,7 +756,7 @@ def solve_traceable(prob: JaxAllocationProblem, method: str = 'alternating',
     """The solver as a pure traceable function — embed in any jit/vmap.
 
     ``early_exit`` lowers every convergence-flagged loop (the outer
-    alternating loop, the SCA rounds, the dual bracket growth, the
+    alternating loop, the SCA rounds, the dual's tree rounds, the
     barrier descent) to a bounded-trip ``lax.while_loop`` that leaves
     when its ``done`` flag fires — bit-identical to the fixed-trip
     lowering, vmap-safe, scan-compilable.  ``inner_tol > 0``
@@ -651,11 +766,14 @@ def solve_traceable(prob: JaxAllocationProblem, method: str = 'alternating',
 
     ``steps`` counts the trips of the innermost serial loops of the
     iterations that ran: for ``alternating`` every golden-section step
-    of every search, for ``barrier`` every descent step with its
-    backtracking halvings.  Alpha's fixed Newton polish is left out;
-    ``uniform`` counts 0.  At ``inner_tol=0`` the count is the same under
-    either lowering; with a tolerance exit the fixed-trip form of
-    ``alternating`` counts every search's and bisection's full schedule.
+    of every search (a search batched over duals counts its steps
+    once), for ``barrier`` every descent step with its backtracking
+    halvings.  Alpha's fixed Newton polish is left out; ``uniform``
+    counts 0.  ``dual_rounds`` counts the dual's tree rounds (0 on
+    ``barrier`` and ``uniform``).  At ``inner_tol=0`` both counts are
+    the same under either lowering; with a tolerance exit the
+    fixed-trip form of ``alternating`` counts every search's full
+    schedule and every level the tolerance would have skipped.
     """
     caps = _caps(prob.A.dtype)
     dtype = prob.A.dtype
@@ -671,23 +789,25 @@ def solve_traceable(prob: JaxAllocationProblem, method: str = 'alternating',
         return JaxAllocation(alpha_u, beta_u, q, p,
                              _objective(prob, caps, alpha_u, beta_u),
                              jnp.int32(0), nan_objs,
-                             jnp.int32(EXIT_CONVERGED), jnp.int32(0))
+                             jnp.int32(EXIT_CONVERGED), jnp.int32(0),
+                             jnp.int32(0))
 
     uniform_obj = _objective(prob, caps, alpha_u, beta_u)
     use_barrier = method == 'barrier'
 
     def body(i, carry):
-        alpha, beta, prev, done, bad_seen, iters, objs, steps = carry
+        (alpha, beta, prev, done, bad_seen, iters, objs, steps,
+         rounds) = carry
         alpha_n = optimize_alpha(prob, beta, n_grid, newton_iters, caps)
         if use_barrier:
             beta_n, steps_n = optimize_beta_barrier(
                 prob, alpha_n, beta, caps=caps, early_exit=early_exit,
                 inner_tol=inner_tol)
+            rounds_n = jnp.int32(0)
         else:
-            beta_n, steps_n = optimize_beta_sca(prob, alpha_n, beta,
-                                                caps=caps,
-                                                early_exit=early_exit,
-                                                inner_tol=inner_tol)
+            beta_n, steps_n, rounds_n = optimize_beta_sca(
+                prob, alpha_n, beta, caps=caps, early_exit=early_exit,
+                inner_tol=inner_tol)
         obj = _objective(prob, caps, alpha_n, beta_n)
         # a non-finite iterate (f32 saturation) must not poison the
         # carry: freeze on the last good point instead of accepting it
@@ -700,13 +820,14 @@ def solve_traceable(prob: JaxAllocationProblem, method: str = 'alternating',
         iters2 = jnp.where(keep, iters, i + 1)
         objs2 = objs.at[i].set(jnp.where(keep, jnp.nan, obj))
         steps2 = jnp.where(done, steps, steps + steps_n)
+        rounds2 = jnp.where(done, rounds, rounds + rounds_n)
         return (alpha2, beta2, prev2, done | conv | bad,
-                bad_seen | (bad & ~done), iters2, objs2, steps2)
+                bad_seen | (bad & ~done), iters2, objs2, steps2, rounds2)
 
     init = (alpha_u, beta_u, jnp.asarray(jnp.inf, dtype),
             jnp.asarray(False), jnp.asarray(False), jnp.int32(0),
-            nan_objs, jnp.int32(0))
-    (alpha, beta, prev, done, bad_seen, iters, objs, steps), _ = (
+            nan_objs, jnp.int32(0), jnp.int32(0))
+    (alpha, beta, prev, done, bad_seen, iters, objs, steps, rounds), _ = (
         _bounded_fori(max_iters, body, init, lambda c: c[3], early_exit))
     # safeguard: never return anything worse than the uniform default.
     # Written NaN-proof (~(prev <= uniform)) so a non-finite objective
@@ -722,7 +843,7 @@ def solve_traceable(prob: JaxAllocationProblem, method: str = 'alternating',
                             jnp.int32(EXIT_ITER_CAP))))
     q, p = success_probs(prob, alpha, beta)
     return JaxAllocation(alpha, beta, q, p, prev, iters, objs, reason,
-                         steps)
+                         steps, rounds)
 
 
 _STATIC = ('method', 'max_iters', 'tol', 'n_grid', 'newton_iters',
@@ -805,6 +926,7 @@ def solve(prob, method: str = 'alternating', max_iters: int = 6,
                       {'iters': iters_used, 'iters_used': iters_used,
                        'exit_reason': int(sol.exit_reason),
                        'steps': int(sol.steps),
+                       'dual_rounds': int(sol.dual_rounds),
                        'method': method, 'backend': 'jax',
                        'objectives': [float(o) for o in
                                       objs[~np.isnan(objs)]]})

@@ -192,7 +192,7 @@ class MetricsRegistry:
         # exit-reason counters make the accuracy-vs-wall-time map
         # reconstructible from the metrics snapshot alone (NaN = path
         # didn't solve)
-        for name in ('alloc_iters', 'alloc_steps'):
+        for name in ('alloc_iters', 'alloc_steps', 'alloc_dual_rounds'):
             effort = row.get(name)
             if effort is not None and not math.isnan(effort):
                 al.gauge(name).set(effort)

@@ -44,7 +44,8 @@ Array = jax.Array
 # (training.fl_loop appends one entry per key per round at flush)
 SCALAR_KEYS = ('payload_bits', 'retransmissions', 'sign_ok_frac',
                'mod_ok_frac', 'q_mean', 'p_mean', 'sign_agreement',
-               'alloc_iters', 'alloc_steps', 'alloc_exit_reason',
+               'alloc_iters', 'alloc_steps', 'alloc_dual_rounds',
+               'alloc_exit_reason',
                'participation_frac',
                'suspect_frac')
 # per-client (K,) vectors serialized into JSONL rows when present
@@ -86,6 +87,9 @@ class RoundTelemetry(NamedTuple):
     #   solver's innermost serial loops this round (golden-section steps
     #   of the alternating solve, descent steps of the barrier; 0 on the
     #   uniform branch)
+    alloc_dual_rounds: Optional[Array] = None  # scalar int32 — tree
+    #   rounds of the alternating solve's dual bisection this round (0
+    #   where no SCA round binds, on the barrier and the uniform branch)
     alloc_exit_reason: Optional[Array] = None  # scalar int32 — the
     #   solver's EXIT_* code (core.allocation_jax: 0 converged,
     #   1 iteration cap, 2 non-finite iterate, 3 uniform fallback)
@@ -105,7 +109,8 @@ class RoundTelemetry(NamedTuple):
                         round_idx: Optional[Array] = None,
                         iters: Optional[Array] = None,
                         exit_reason: Optional[Array] = None,
-                        steps: Optional[Array] = None
+                        steps: Optional[Array] = None,
+                        dual_rounds: Optional[Array] = None
                         ) -> 'RoundTelemetry':
         """Attach the round's allocation state (device arrays, no host
         transfer — pure ``_replace``)."""
@@ -120,6 +125,8 @@ class RoundTelemetry(NamedTuple):
             kw['alloc_exit_reason'] = exit_reason
         if steps is not None:
             kw['alloc_steps'] = steps
+        if dual_rounds is not None:
+            kw['alloc_dual_rounds'] = dual_rounds
         return self._replace(**kw)
 
     def condensed(self) -> 'RoundTelemetry':
@@ -173,6 +180,8 @@ def round_scalars(t: RoundTelemetry) -> Dict[str, Array]:
             t.alloc_iters, jnp.float32),
         'alloc_steps': nan if t.alloc_steps is None else jnp.asarray(
             t.alloc_steps, jnp.float32),
+        'alloc_dual_rounds': nan if t.alloc_dual_rounds is None
+        else jnp.asarray(t.alloc_dual_rounds, jnp.float32),
         'alloc_exit_reason': nan if t.alloc_exit_reason is None
         else jnp.asarray(t.alloc_exit_reason, jnp.float32),
         'participation_frac': nan if t.active is None else jnp.mean(
@@ -224,6 +233,8 @@ def to_row(t: RoundTelemetry, round_idx: Optional[int] = None
         else _np_scalar(t.alloc_iters),
         'alloc_steps': math.nan if t.alloc_steps is None
         else _np_scalar(t.alloc_steps),
+        'alloc_dual_rounds': math.nan if t.alloc_dual_rounds is None
+        else _np_scalar(t.alloc_dual_rounds),
         'alloc_exit_reason': math.nan if t.alloc_exit_reason is None
         else _np_scalar(t.alloc_exit_reason),
         'alloc_objective': None if t.alloc_objective is None

@@ -230,11 +230,13 @@ def make_fused_fl_round(cfg: ModelConfig, fl: FLConfig,
                                           max_iters=max_iters,
                                           tol=alloc_tol,
                                           early_exit=early_exit)
-            return s.q, s.p, s.objective, s.iters, s.exit_reason, s.steps
+            return (s.q, s.p, s.objective, s.iters, s.exit_reason, s.steps,
+                    s.dual_rounds)
 
         def uniform(_):
             s = alloc_jax.solve_traceable(prob, 'uniform')
-            return s.q, s.p, s.objective, s.iters, s.exit_reason, s.steps
+            return (s.q, s.p, s.objective, s.iters, s.exit_reason, s.steps,
+                    s.dual_rounds)
 
         if method == 'uniform':
             return uniform(None)
@@ -271,10 +273,10 @@ def make_fused_fl_round(cfg: ModelConfig, fl: FLConfig,
                 p_w_n, byz_n, present = p_w, byz_mask, None
             active = pop.combine_active(present, draw_active(key))
 
-        obj = iters = reason = steps = None
+        obj = iters = reason = steps = rounds = None
         if transport_kind == 'spfl':
             with stage_scope('alloc_solve'):
-                q, p, obj, iters, reason, steps = alloc_f32(
+                q, p, obj, iters, reason, steps, rounds = alloc_f32(
                     grads, gbar, stats, gains, p_w_n)
             ghat, _, diag = tr.spfl_aggregate_tree(
                 grads, gbar, q, p, fl, key, stats=stats, wire=fl.wire,
@@ -297,8 +299,8 @@ def make_fused_fl_round(cfg: ModelConfig, fl: FLConfig,
         with stage_scope('telemetry'):
             rec = diag.with_allocation(q, p, objective=obj,
                                        round_idx=round_idx, iters=iters,
-                                       exit_reason=reason,
-                                       steps=steps).condensed()
+                                       exit_reason=reason, steps=steps,
+                                       dual_rounds=rounds).condensed()
             if cohort is not None:
                 rec = rec._replace(cohort_ids=cohort.ids)
         return new_params, new_opt, new_gbar, rec, loss_mean

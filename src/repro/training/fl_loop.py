@@ -88,6 +88,8 @@ class FLHistory:
     # iterations to converge (NaN on rounds/paths without a solve)
     alloc_steps: List[float] = field(default_factory=list)     # solver inner
     # loop trips (RoundTelemetry.alloc_steps; NaN likewise)
+    alloc_dual_rounds: List[float] = field(default_factory=list)  # dual
+    # bisection tree rounds (RoundTelemetry.alloc_dual_rounds; NaN likewise)
     alloc_exit_reason: List[float] = field(default_factory=list)  # EXIT_*
     retransmissions: List[float] = field(default_factory=list)
     # adversarial-cohort telemetry (populated when the knobs are on):
@@ -315,26 +317,26 @@ class FLSimulator:
                                                   tol=alloc_tol,
                                                   early_exit=early_exit)
                     return (s.alpha, s.beta, s.q, s.p, s.objective,
-                            s.iters, s.exit_reason, s.steps)
+                            s.iters, s.exit_reason, s.steps, s.dual_rounds)
 
                 def uniform(_):
                     s = alloc_jax.solve_traceable(prob, 'uniform')
                     return (s.alpha, s.beta, s.q, s.p, s.objective,
-                            s.iters, s.exit_reason, s.steps)
+                            s.iters, s.exit_reason, s.steps, s.dual_rounds)
 
                 if method == 'uniform':
-                    alpha, beta, q, p, obj, iters, reason, steps = uniform(
-                        None)
+                    (alpha, beta, q, p, obj, iters, reason, steps,
+                     rounds) = uniform(None)
                 else:
                     # no compensation history yet (round 0): optimizing
                     # against gbar=0 degenerates to alpha=1 / ghat=0
-                    (alpha, beta, q, p, obj, iters, reason,
-                     steps) = jax.lax.cond(jnp.max(gb2) > 0.0, solved,
-                                           uniform, None)
+                    (alpha, beta, q, p, obj, iters, reason, steps,
+                     rounds) = jax.lax.cond(jnp.max(gb2) > 0.0, solved,
+                                            uniform, None)
                 return (q.astype(jnp.float32), p.astype(jnp.float32),
                         alpha.astype(jnp.float32),
                         beta.astype(jnp.float32), obj, iters, reason,
-                        steps)
+                        steps, rounds)
 
             # traced (and always re-entered) under x64: the closed forms
             # overflow f32 — see allocation_jax's precision contract
@@ -435,12 +437,12 @@ class FLSimulator:
                                               tol=alloc_tol,
                                               early_exit=early_exit)
                 return (s.q, s.p, s.objective, s.iters, s.exit_reason,
-                        s.steps)
+                        s.steps, s.dual_rounds)
 
             def uniform(_):
                 s = alloc_jax.solve_traceable(prob, 'uniform')
                 return (s.q, s.p, s.objective, s.iters, s.exit_reason,
-                        s.steps)
+                        s.steps, s.dual_rounds)
 
             if method == 'uniform':
                 return uniform(None)
@@ -507,10 +509,10 @@ class FLSimulator:
                 # arrivals (ragged cohorts) compose with in-round stalls
                 active = pop.combine_active(present, s_active)
 
-            obj = iters = reason = steps = None
+            obj = iters = reason = steps = rounds = None
             if allocating:
                 with stage_scope('alloc_solve'):
-                    q, p, obj, iters, reason, steps = alloc_f32(
+                    q, p, obj, iters, reason, steps, rounds = alloc_f32(
                         grads, gbar, gains_n, p_w_n)
             else:
                 q = jnp.ones(self.K)
@@ -536,7 +538,8 @@ class FLSimulator:
             with stage_scope('telemetry'):
                 rec = diag.with_allocation(
                     q, p, objective=obj, round_idx=n, iters=iters,
-                    exit_reason=reason, steps=steps).condensed()
+                    exit_reason=reason, steps=steps,
+                    dual_rounds=rounds).condensed()
                 if population:
                     rec = rec._replace(cohort_ids=cohort.ids)
             return new_params, gbar2, z2, st2, rec, loss_mean
@@ -657,6 +660,7 @@ class FLSimulator:
                     hist.sign_agreement.append(row['sign_agreement'])
                 hist.alloc_iters.append(row['alloc_iters'])
                 hist.alloc_steps.append(row['alloc_steps'])
+                hist.alloc_dual_rounds.append(row['alloc_dual_rounds'])
                 hist.alloc_exit_reason.append(row['alloc_exit_reason'])
                 hist.retransmissions.append(row['retransmissions'])
                 if fl.dropout_rate > 0.0 or (
@@ -754,6 +758,7 @@ class FLSimulator:
                     hist.sign_agreement.append(row['sign_agreement'])
                 hist.alloc_iters.append(row['alloc_iters'])
                 hist.alloc_steps.append(row['alloc_steps'])
+                hist.alloc_dual_rounds.append(row['alloc_dual_rounds'])
                 hist.alloc_exit_reason.append(row['alloc_exit_reason'])
                 hist.retransmissions.append(row['retransmissions'])
                 if fl.dropout_rate > 0.0 or (
@@ -799,6 +804,7 @@ class FLSimulator:
 
             ta = time.time()
             alloc_obj = alloc_iters = alloc_reason = alloc_steps = None
+            alloc_rounds = None
             with self.trace.span('alloc_solve'):
                 if kind in ('spfl', 'spfl_retx'):
                     if pop_mode:
@@ -815,7 +821,8 @@ class FLSimulator:
                         # x64 re-entry keeps the jit cache key stable)
                         with jax.enable_x64():
                             (q, p, _, _, alloc_obj, alloc_iters,
-                             alloc_reason, alloc_steps) = self._alloc_jax(
+                             alloc_reason, alloc_steps,
+                             alloc_rounds) = self._alloc_jax(
                                 grads, self.gbar, gains_n, p_w_n)
                         sol, stats = None, None
                     else:
@@ -875,7 +882,7 @@ class FLSimulator:
                 q, p, objective=alloc_obj,
                 round_idx=jnp.uint32(self._round - 1),
                 iters=alloc_iters, exit_reason=alloc_reason,
-                steps=alloc_steps).condensed()
+                steps=alloc_steps, dual_rounds=alloc_rounds).condensed()
             if pop_mode:
                 rec = rec._replace(cohort_ids=cohort.ids)
             if ring is None:

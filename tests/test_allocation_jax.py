@@ -20,6 +20,7 @@ The contract under test (documented in src/repro/core/README.md):
   descent per iteration — only the final uniform safeguard).
 """
 import dataclasses
+import functools
 
 import jax
 import numpy as np
@@ -420,72 +421,133 @@ def test_inner_tol_frontier_within_contract(method):
 # solver step counter (JaxAllocation.steps -> RoundTelemetry.alloc_steps)
 # ---------------------------------------------------------------------------
 
-# the duals the bracket growth tries: 1, 10, ..., 1e29 (30 trips at most)
-_GROW_DUALS = [0.0] + [10.0 ** i for i in range(30)]
-
-
 @jax.jit
-def _one_sca_round(prob, alpha, beta, duals):
-    """One SCA round from ``beta``: its step count, and the bandwidth sum
-    of the free search (the one ``optimize_beta_sca`` runs) at each of
-    ``duals``."""
+def _serial_search(prob, alpha, beta, lam):
+    """One golden-section search of an SCA round's dual schedule at dual
+    ``lam``: the searched beta and its bandwidth sum."""
     surrogate = AJ._surrogate(prob, AJ._caps(prob.A.dtype), alpha, beta)
-
-    def total(lam):
-        b, _ = AJ._golden_vec(lambda x: surrogate(x) + lam * x,
-                              beta.shape, beta.dtype)
-        return AJ._msum(prob, b)
-
-    steps = AJ.optimize_beta_sca(prob, alpha, beta, sca_rounds=1)[1]
-    return steps, jax.vmap(total)(duals)
+    b, _ = AJ._golden_vec(lambda x: surrogate(x) + lam * x, beta.shape,
+                          beta.dtype)
+    return b, AJ._msum(prob, b)
 
 
-def _sca_searches_by_hand(totals):
-    """Golden-section searches one SCA round runs, counted on the host
-    from the searches' own answers (``totals`` at ``_GROW_DUALS``): the
-    free search, and when its bandwidths overshoot the simplex, one
-    search per dual bracket growth (x10 from 1 while the sum stays
-    infeasible, 30 at most), one per bisection step and the final one."""
-    if totals[0] <= 1.0:
-        return 1
-    grows = 0
-    for hi, t in zip(_GROW_DUALS[1:], totals[1:]):
-        grows += 1
-        if not (t > 1.0 and hi < 1e30):
+@functools.partial(jax.jit, static_argnames='bound')
+def _serial_accept(prob, alpha, beta, b, bound):
+    """The end of an SCA round: scale a bound beta onto the simplex, keep
+    it only on descent of the true objective."""
+    caps = AJ._caps(prob.A.dtype)
+    if bound:
+        b = b * jax.numpy.minimum(1.0, 1.0 / jax.numpy.maximum(
+            AJ._msum(prob, b), 1e-12))
+    cur = AJ._objective(prob, caps, alpha, b)
+    return jax.numpy.where(cur <= AJ._objective(prob, caps, alpha, beta),
+                           b, beta)
+
+
+def _serial_sca_round(prob, alpha, beta):
+    """One SCA round on the serial schedule, driven from the host with one
+    search per step: the free search; when its bandwidths overshoot the
+    simplex, up to ``DUAL_GROWTHS`` bracket growths x10 from 1, all
+    ``BISECTION_ITERS`` bisection steps and a final search at ``hi``.
+    Returns the round's beta and the bisection levels that ran before
+    the midpoint first equalled an end of the bracket (None when the
+    free search fits)."""
+    one = np.dtype(beta.dtype).type
+    b, total = _serial_search(prob, alpha, beta, one(0.0))
+    if not total > 1.0:
+        return _serial_accept(prob, alpha, beta, b, False), None
+    hi = one(1.0)
+    for _ in range(AJ.DUAL_GROWTHS):
+        _, total = _serial_search(prob, alpha, beta, hi)
+        if not (total > 1.0 and hi < one(1e30)):
             break
-    return 1 + grows + AJ.BISECTION_ITERS + 1
+        hi = hi * one(10.0)
+    lo, levels, moving = one(0.0), 0, True
+    for _ in range(AJ.BISECTION_ITERS):
+        mid = one(0.5) * (lo + hi)
+        moving = moving and mid != lo and mid != hi
+        levels += moving
+        _, total = _serial_search(prob, alpha, beta, mid)
+        lo, hi = (mid, hi) if total > 1.0 else (lo, mid)
+    b, _ = _serial_search(prob, alpha, beta, hi)
+    return _serial_accept(prob, alpha, beta, b, True), levels
 
 
-@pytest.mark.parametrize('k,latency,searches', [(1, None, 1),
-                                                (4, None, 63),
-                                                (4, 0.002, 92)])
-def test_alloc_steps_hand_count_one_sca_round(k, latency, searches):
-    """One SCA round's step count is GOLDEN_ITERS per search, with the
-    searches counted by hand: a problem whose free search fits the
-    simplex (K=1), one that binds after one bracket growth, and one whose
-    bracket grows to its 30-trip cap (a latency budget tight enough that
-    every dual below 1e29 leaves the sum infeasible)."""
-    prob = _problem(k=k, power_dbm=-14.0, seed=3)
+_one_sca_round = jax.jit(functools.partial(AJ.optimize_beta_sca,
+                                           sca_rounds=1))
+
+# one SCA round from the uniform point: K=1, whose free search fits; K=4,
+# bound after one bracket growth; K=4 under a latency budget tight enough
+# that every dual below 1e29 leaves the sum infeasible (growth to its
+# 30-trip cap); K=20 at the paper cell's settings (-4 dBm, 62,006 params)
+_SCA_CASES = {'k1-free': (1, -14.0, 3, None, 60000),
+              'k4-grow-once': (4, -14.0, 3, None, 60000),
+              'k4-growth-cap': (4, -14.0, 3, 0.002, 60000),
+              'k20-paper-cell': (20, -4.0, 7, None, 62006)}
+
+
+def _sca_round_inputs(case, dtype):
+    k, power, seed, latency, dim = _SCA_CASES[case]
+    prob = _problem(k=k, power_dbm=power, seed=seed, dim=dim)
     if latency is not None:
         prob = dataclasses.replace(
             prob, fl=dataclasses.replace(prob.fl, latency_s=latency))
+    jp = AJ.from_reference(prob, dtype=dtype)
+    return (jp, jax.numpy.full((k,), 0.5, dtype),
+            jax.numpy.full((k,), 1.0 / k, dtype))
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'float64'])
+@pytest.mark.parametrize('case', list(_SCA_CASES))
+def test_dual_tree_bit_matches_serial_bisection(case, dtype):
+    """One SCA round's beta from the batched growth search and the tree
+    rounds equals, bit for bit, the serial schedule's: every growth and
+    all 60 bisection levels as single searches.  In float32 the tree
+    stops at the exact fixed point, well before 60 levels."""
+    with jax.enable_x64(dtype == 'float64'):
+        jp, alpha, beta = _sca_round_inputs(case, dtype)
+        want, levels = _serial_sca_round(jp, alpha, beta)
+        got, _, rounds = _one_sca_round(jp, alpha, beta)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (levels is None) == (case == 'k1-free')
+    if levels is not None:
+        assert int(rounds) == -(-levels // AJ.TREE_DEPTH)
+        if dtype == 'float32':
+            assert levels < AJ.BISECTION_ITERS
+            assert int(rounds) * AJ.TREE_DEPTH < AJ.BISECTION_ITERS
+
+
+@pytest.mark.parametrize('case,searches', [('k1-free', 1),
+                                           ('k4-grow-once', 10),
+                                           ('k4-growth-cap', 9)])
+def test_alloc_steps_hand_count_one_sca_round(case, searches):
+    """One SCA round's step count is GOLDEN_ITERS per search, with the
+    searches counted by hand: one search over lambda = 0 and the growth
+    candidates, and when the sum binds, one per tree round, i.e. the
+    serial bisection levels until the midpoint equals an end of the
+    bracket (or all BISECTION_ITERS) in blocks of TREE_DEPTH.  The cases
+    are a problem whose free search fits the simplex (K=1), one that
+    binds after one bracket growth, and one whose bracket grows to its
+    30-trip cap."""
     with jax.enable_x64():
-        jp = AJ.from_reference(prob)
-        beta = jax.numpy.full((k,), 1.0 / k)
-        alpha = jax.numpy.full((k,), 0.5)
-        steps, totals = _one_sca_round(
-            jp, alpha, beta, jax.numpy.asarray(_GROW_DUALS))
-        assert _sca_searches_by_hand(np.asarray(totals)) == searches
-        assert int(steps) == searches * AJ.GOLDEN_ITERS
+        jp, alpha, beta = _sca_round_inputs(case, 'float64')
+        _, levels = _serial_sca_round(jp, alpha, beta)
+        _, steps, rounds = _one_sca_round(jp, alpha, beta)
+    by_hand = 1 if levels is None else 1 + -(-levels // AJ.TREE_DEPTH)
+    assert by_hand == searches
+    assert int(rounds) == searches - 1
+    assert int(steps) == searches * AJ.GOLDEN_ITERS
 
 
 @pytest.mark.parametrize('method', ['alternating', 'barrier'])
 def test_alloc_steps_lowering_invariant_and_zero_on_uniform(method):
-    """The whole solve's count is the same under the early-exit and the
-    fixed-trip lowering (frozen trips are not counted) and a whole number
-    of units: GOLDEN_ITERS per search for ``alternating``, one descent
-    step and its BACKTRACK_ITERS halvings for ``barrier``.  ``uniform``
-    counts 0."""
+    """The whole solve's counts are the same under the early-exit and the
+    fixed-trip lowering (frozen trips are not counted).  Steps come in
+    whole units: GOLDEN_ITERS per search for ``alternating``, one descent
+    step and its BACKTRACK_ITERS halvings for ``barrier``.  Dual tree
+    rounds run only where an SCA round binds: never on ``barrier`` or
+    ``uniform``, nor on a one-client problem whose free search always
+    fits.  ``uniform`` counts 0 steps."""
     unit = (AJ.GOLDEN_ITERS if method == 'alternating'
             else 1 + AJ.BACKTRACK_ITERS)
     for power, seed in [(-8.0, 41), (-24.0, 6)]:
@@ -494,4 +556,15 @@ def test_alloc_steps_lowering_invariant_and_zero_on_uniform(method):
         ft = AJ.solve(prob, method, max_iters=3, early_exit=False)
         assert ee.info['steps'] == ft.info['steps'] > 0
         assert ee.info['steps'] % unit == 0
-    assert AJ.solve(prob, 'uniform').info['steps'] == 0
+        assert ee.info['dual_rounds'] == ft.info['dual_rounds']
+        assert (ee.info['dual_rounds'] > 0) == (method == 'alternating')
+    uniform = AJ.solve(prob, 'uniform')
+    assert uniform.info['steps'] == uniform.info['dual_rounds'] == 0
+    if method == 'alternating':
+        free = _problem(k=1, power_dbm=-14.0, seed=3)
+        counts = {(s.info['steps'], s.info['dual_rounds'])
+                  for s in (AJ.solve(free, method, max_iters=3,
+                                     early_exit=ee)
+                            for ee in (True, False))}
+        (steps, rounds), = counts
+        assert steps > 0 and rounds == 0

@@ -479,6 +479,26 @@ def test_alloc_steps_survives_ring_flush_and_serializers():
     assert al['alloc_steps_hist']['count'] == 1
 
 
+def test_alloc_dual_rounds_survives_ring_flush_and_serializers():
+    """``alloc_dual_rounds`` rides the record like ``alloc_steps``: ring
+    push, flush, ``to_row``/``round_scalars`` and the allocation
+    channel; NaN on a record that solved nothing."""
+    rec = _mk_rec().with_allocation(
+        jnp.full((K,), 0.9), jnp.full((K,), 0.6), iters=jnp.int32(2),
+        steps=jnp.int32(1344), dual_rounds=jnp.int32(24))
+    ring = ring_push(ring_init(rec, 2), rec)
+    rows, _ = obs_ring.flush(ring)
+    row = to_row(rows[0])
+    assert row['alloc_dual_rounds'] == 24.0
+    assert float(round_scalars(rows[0])['alloc_dual_rounds']) == 24.0
+    assert math.isnan(to_row(_mk_rec())['alloc_dual_rounds'])
+    reg = MetricsRegistry()
+    reg.observe_round(row)
+    al = reg.snapshot()['allocation']
+    assert al['alloc_dual_rounds']['value'] == 24.0
+    assert al['alloc_dual_rounds_hist']['count'] == 1
+
+
 def _hlo_sources(hlo: str):
     """[(op_name, innermost repro/ file)] of the HLO instructions whose
     metadata names a stack frame (the compiled module's frame tables)."""
